@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
+from repro.compile_cache import enable_compile_cache
 # importing the subsystems up front registers their ledger collectors, so
 # every section's telemetry snapshot carries the full unified series set
 # (the serving_queue section runs before any block store exists)
@@ -749,6 +750,7 @@ def main(argv=None):
                     help="2-repeat schema-validation pass; writes to a "
                          "scratch file instead of BENCH_query.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         args.repeats = min(args.repeats, 2)
     out_path = args.out or str(

@@ -28,16 +28,17 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from concurrent.futures import ThreadPoolExecutor
+from functools import partial
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
 from .hashing import make_hash_family
-from .index import IndexArrays, build_index
+from .index import IndexArrays, build_host_index
 from .probabilities import LSHParams, solve_params
 from .query import QueryConfig, QueryResult, fused_plan_body, oracle_plan_body
 
@@ -50,7 +51,10 @@ _INVALID = np.int32(2**31 - 1)
 @dataclasses.dataclass
 class ShardedIndexArrays:
     """Typed per-shard index pytree; non-replicated leaves carry a leading
-    shard dim (hash family a/b/rm stays replicated — shared across shards)."""
+    shard dim (hash family a/b/rm stays replicated — shared across shards).
+
+    `build_sharded_index` returns it with host numpy leaves; `to_mesh`
+    places it (SearchEngine does so when given a mesh)."""
 
     arrays: IndexArrays       # stacked: [SH, ...] except IndexArrays.REPLICATED
     shard_offsets: jnp.ndarray  # [SH] global id base per shard
@@ -60,19 +64,24 @@ class ShardedIndexArrays:
     def specs(self, index_axes) -> IndexArrays:
         """PartitionSpec pytree matching `arrays`: shard dim over
         `index_axes`, hash family replicated."""
-        index_axes = tuple(index_axes)
+        return _stacked_specs(self.arrays, tuple(index_axes))
 
-        def spec(name: str, v) -> P:
-            if name in IndexArrays.REPLICATED:
-                return P(*([None] * np.ndim(v)))
-            return P(index_axes, *([None] * (np.ndim(v) - 1)))
+    def to_mesh(self, mesh: Mesh,
+                index_axes=("shard",)) -> "ShardedIndexArrays":
+        """Place every leaf on `mesh` straight from host memory: each device
+        receives only its own shard (replicated leaves go to all), so an
+        index larger than one chip never lands whole on device 0."""
+        specs = self.specs(index_axes)
 
-        return IndexArrays(
-            **{name: spec(name, getattr(self.arrays, name))
-               for name in IndexArrays.array_fields()},
-            block_objs=self.arrays.block_objs,
-            lane_pad=self.arrays.lane_pad,
-        )
+        def put(v, spec):
+            return jax.device_put(np.asarray(v), NamedSharding(mesh, spec))
+
+        arrays = dataclasses.replace(self.arrays, **{
+            name: put(getattr(self.arrays, name), getattr(specs, name))
+            for name in IndexArrays.array_fields()})
+        return dataclasses.replace(
+            self, arrays=arrays,
+            shard_offsets=put(self.shard_offsets, P(tuple(index_axes))))
 
     def with_block_objs(self, block_objs: int,
                         lane_pad: Optional[int] = None) -> "ShardedIndexArrays":
@@ -93,16 +102,15 @@ class ShardedIndexArrays:
                 np.asarray(ix.entries_id[s]), np.asarray(ix.entries_fp[s]),
                 np.asarray(ix.table_off[s]), np.asarray(ix.table_cnt[s]),
                 int(block_objs), lane_pad=lp)
-            per_shard.append((np.asarray(ids_b), np.asarray(fps_b),
-                              np.asarray(head)))
+            per_shard.append((ids_b, fps_b, head))
         NB_max = max(p[0].shape[0] for p in per_shard)
         arrays = dataclasses.replace(
             ix,
-            ids_blocks=jnp.asarray(np.stack(
-                [_pad_rows(p[0], NB_max, int(_INVALID)) for p in per_shard])),
-            fps_blocks=jnp.asarray(np.stack(
-                [_pad_rows(p[1], NB_max, -1) for p in per_shard])),
-            blocks_head=jnp.asarray(np.stack([p[2] for p in per_shard])),
+            ids_blocks=np.stack(
+                [_pad_rows(p[0], NB_max, int(_INVALID)) for p in per_shard]),
+            fps_blocks=np.stack(
+                [_pad_rows(p[1], NB_max, -1) for p in per_shard]),
+            blocks_head=np.stack([p[2] for p in per_shard]),
             block_objs=int(block_objs), lane_pad=lp,
         )
         return dataclasses.replace(self, arrays=arrays)
@@ -176,6 +184,19 @@ class ShardedIndexArrays:
             stats=stats)
 
 
+def _stacked_specs(arrays: IndexArrays, index_axes: tuple) -> IndexArrays:
+    def spec(name: str, v) -> P:
+        if name in IndexArrays.REPLICATED:
+            return P(*([None] * np.ndim(v)))
+        return P(index_axes, *([None] * (np.ndim(v) - 1)))
+
+    return IndexArrays(
+        **{name: spec(name, getattr(arrays, name))
+           for name in IndexArrays.array_fields()},
+        block_objs=arrays.block_objs, lane_pad=arrays.lane_pad,
+    )
+
+
 def _pad_rows(x: np.ndarray, rows: int, fill) -> np.ndarray:
     pad = rows - x.shape[0]
     if pad == 0:
@@ -201,7 +222,9 @@ def build_sharded_index(
     by `build_index`; entry arrays, block stores, and the db tier are padded
     to the max shard extent so the stacked pytree is rectangular (padding is
     masked: pad entries sit behind table_cnt, pad block rows behind
-    blocks_head, pad db rows behind the entry ids)."""
+    blocks_head, pad db rows behind the entry ids). Every leaf stays in host
+    numpy: `SearchEngine(sharded, mesh=...)` places each shard on its own
+    device (`ShardedIndexArrays.to_mesh`)."""
     db = np.asarray(db)
     n, d = db.shape
     bounds = np.linspace(0, n, num_shards + 1).astype(np.int64)
@@ -221,12 +244,15 @@ def build_sharded_index(
         w=params.w, u=params.u, fp_bits=params.fp_bits,
     )
 
-    per_shard = []
-    for s in range(num_shards):
+    def build_shard(s: int) -> IndexArrays:
         lo, hi = int(bounds[s]), int(bounds[s + 1])
-        shard_db = db[lo:hi]
         sp = dataclasses.replace(params, n=hi - lo)
-        per_shard.append(build_index(shard_db, sp, family=family).arrays)
+        return build_host_index(db[lo:hi], sp, family=family).arrays
+
+    # the shard builds are independent host work, and numpy releases the
+    # GIL in their heavy array ops, so they overlap on a multi-core host
+    with ThreadPoolExecutor(max_workers=num_shards) as pool:
+        per_shard = list(pool.map(build_shard, range(num_shards)))
 
     E_max = max(int(ix.entries_id.shape[0]) for ix in per_shard)
     NB_max = max(int(ix.ids_blocks.shape[0]) for ix in per_shard)
@@ -239,10 +265,11 @@ def build_sharded_index(
         parts = [np.asarray(getattr(ix, name)) for ix in per_shard]
         if name in rows:
             parts = [_pad_rows(p, rows[name], fills[name]) for p in parts]
-        return jnp.asarray(np.stack(parts))
+        return np.stack(parts)
 
     arrays = IndexArrays(
-        a=family.a, b=family.b, rm=family.rm,  # replicated (no shard dim)
+        a=np.asarray(family.a), b=np.asarray(family.b),  # replicated
+        rm=np.asarray(family.rm),
         **{name: stack(name) for name in IndexArrays.array_fields()
            if name not in IndexArrays.REPLICATED},
         block_objs=per_shard[0].block_objs,
@@ -250,7 +277,7 @@ def build_sharded_index(
     )
     return ShardedIndexArrays(
         arrays=arrays,
-        shard_offsets=jnp.asarray(bounds[:-1].astype(np.int32)),
+        shard_offsets=bounds[:-1].astype(np.int32),
         params=params,
         num_shards=num_shards,
     )
@@ -352,24 +379,36 @@ def sharded_query_result(
         s_cap=int(cap), block_objs=(bo if bo != p.block_objs else None))
     if valid is None:
         valid = jnp.ones((queries.shape[0],), dtype=bool)
-
-    qspec = P(query_axes if query_axes else None)
-    in_specs = (sharded.specs(index_axes), P(index_axes), qspec, qspec)
-    out_specs = (qspec,) * 7
-
-    def body(ix, shard_off, qs, ok):
-        return _local_shard_query(_local_view(ix), shard_off[0], qs, ok, cfg,
-                                  index_axes, k, local_plan)
-
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    ids, dists, found, radii, nio_t, nio_b, cands = fn(
+    ids, dists, found, radii, nio_t, nio_b, cands = _sharded_program(
         sharded.arrays, sharded.shard_offsets, queries.astype(jnp.float32),
-        valid.astype(bool))
+        valid.astype(bool), mesh=mesh, cfg=cfg, index_axes=index_axes,
+        query_axes=query_axes, k=k, local_plan=local_plan)
     return QueryResult(
         ids=ids, dists=dists, found=found, radii_searched=radii,
         nio_table=nio_t, nio_blocks=nio_b, cands_checked=cands,
         probe_sizes=None,
     )
+
+
+# One jitted program, never eager shard_map: run eagerly, shard_map
+# dispatches op by op and materializes a copy of each device's whole shard
+# (the `[0]` of _local_view), which runs a chip out of HBM once an index
+# fills ~40% of it; it also fuses differently from the queue's jitted path.
+@partial(jax.jit, static_argnames=("mesh", "cfg", "index_axes", "query_axes",
+                                   "k", "local_plan"))
+def _sharded_program(arrays, shard_offsets, queries, valid, *, mesh, cfg,
+                     index_axes, query_axes, k, local_plan):
+    qspec = P(query_axes if query_axes else None)
+    in_specs = (_stacked_specs(arrays, index_axes), P(index_axes), qspec,
+                qspec)
+
+    def body(ix, shard_off, qs, ok):
+        return _local_shard_query(_local_view(ix), shard_off[0], qs, ok, cfg,
+                                  index_axes, k, local_plan)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(qspec,) * 7, check_vma=False)(
+        arrays, shard_offsets, queries, valid)
 
 
 def make_sharded_query_fn(sharded: ShardedIndexArrays, mesh: Mesh, **kw):

@@ -116,7 +116,8 @@ def _split_bucket_fp(hv32: jnp.ndarray, u: int, fp_bits: int):
 def _hash_points_impl(x, a_t, b_t, rm_t, wr, u, fp_bits):
     # x: [N, d]; a_t: [L, m, d]; b_t/rm_t: [L, m]; wr: scalar effective width.
     L, m, d = a_t.shape
-    proj = jnp.einsum("nd,lmd->nlm", x, a_t, preferred_element_type=jnp.float32)
+    proj = jnp.einsum("nd,lmd->nlm", x, a_t, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
     hj = jnp.floor((proj + b_t[None] * wr) / wr).astype(jnp.int32)  # [N, L, m]
     hv32 = _combine(hj, rm_t[None].astype(jnp.uint32))  # [N, L]
     return _split_bucket_fp(hv32, u, fp_bits)

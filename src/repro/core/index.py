@@ -41,7 +41,8 @@ from .probabilities import LSHParams
 from ..kernels.bucket_probe.ops import blockify_entries
 from ..kernels.dispatch import native_lane_pad
 
-__all__ = ["IndexArrays", "E2LSHIndex", "build_index", "IndexStats"]
+__all__ = ["IndexArrays", "E2LSHIndex", "build_index", "build_host_index",
+           "IndexStats"]
 
 def _static():
     return dataclasses.field(metadata=dict(static=True))
@@ -92,15 +93,23 @@ class IndexArrays:
                      if f.name not in ("block_objs", "lane_pad"))
 
     @staticmethod
-    def from_csr(*, a, b, rm, table_off, table_cnt, entries_id, entries_fp,
-                 db, block_objs: int, lane_pad: Optional[int] = None,
-                 db_norm2=None) -> "IndexArrays":
-        """Blockify a CSR layout into the native block-store representation.
+    def from_csr(**csr) -> "IndexArrays":
+        """Blockify a CSR layout into the native block-store representation,
+        placed on the default device (`host_from_csr`'s arguments).
 
         This is the one conversion path in core; `build_index` calls it with
         the freshly packed numpy CSR so the device arrays are born
         blockified.
         """
+        return jax.tree.map(jnp.asarray, IndexArrays.host_from_csr(**csr))
+
+    @staticmethod
+    def host_from_csr(*, a, b, rm, table_off, table_cnt, entries_id,
+                      entries_fp, db, block_objs: int,
+                      lane_pad: Optional[int] = None,
+                      db_norm2=None) -> "IndexArrays":
+        """`from_csr` with every leaf left in host numpy (the sharded build
+        stacks host shards and places each on its own device)."""
         lp = native_lane_pad() if lane_pad is None else int(lane_pad)
         ids_b, fps_b, head, _ = blockify_entries(
             np.asarray(entries_id), np.asarray(entries_fp),
@@ -111,14 +120,14 @@ class IndexArrays:
             db_norm2 = np.sum(np.asarray(db, np.float32) ** 2, axis=-1,
                               dtype=np.float32)
         return IndexArrays(
-            a=jnp.asarray(a), b=jnp.asarray(b), rm=jnp.asarray(rm),
+            a=np.asarray(a), b=np.asarray(b), rm=np.asarray(rm),
             ids_blocks=ids_b, fps_blocks=fps_b, blocks_head=head,
-            table_off=jnp.asarray(table_off, jnp.int32),
-            table_cnt=jnp.asarray(table_cnt, jnp.int32),
-            entries_id=jnp.asarray(entries_id),
-            entries_fp=jnp.asarray(entries_fp),
-            db=jnp.asarray(db, jnp.float32),
-            db_norm2=jnp.asarray(db_norm2, jnp.float32),
+            table_off=np.asarray(table_off, np.int32),
+            table_cnt=np.asarray(table_cnt, np.int32),
+            entries_id=np.asarray(entries_id),
+            entries_fp=np.asarray(entries_fp),
+            db=np.asarray(db, np.float32),
+            db_norm2=np.asarray(db_norm2, np.float32),
             block_objs=int(block_objs), lane_pad=lp,
         )
 
@@ -280,7 +289,14 @@ def _pack_radius_table(
     return toff, tcnt, ids_parts, fp_parts, nonempty, storage_blocks, max_bucket
 
 
-def build_index(
+def build_index(db: np.ndarray, params: LSHParams, **kw) -> E2LSHIndex:
+    """`build_host_index`, with the arrays placed on the default device."""
+    index = build_host_index(db, params, **kw)
+    return dataclasses.replace(
+        index, arrays=jax.tree.map(jnp.asarray, index.arrays))
+
+
+def build_host_index(
     db: np.ndarray,
     params: LSHParams,
     *,
@@ -298,7 +314,8 @@ def build_index(
     a float64 accumulation on the host, making index contents reproducible
     across processes and thread counts (the device GEMM's reduction order is
     thread-count-dependent). Set False to hash on the accelerator (faster
-    for huge builds, not bit-reproducible across hosts).
+    for huge builds, not bit-reproducible across hosts). Every array leaf
+    stays in host numpy.
     """
     db = np.asarray(db)
     n, d = db.shape
@@ -373,7 +390,7 @@ def build_index(
     )
     if entries_id.shape[0] >= 2**31:
         raise ValueError("entry space exceeds int32 addressing; shard the index")
-    arrays = IndexArrays.from_csr(
+    arrays = IndexArrays.host_from_csr(
         a=family.a, b=family.b, rm=family.rm,
         table_off=toff_all.astype(np.int32), table_cnt=tcnt_all,
         entries_id=entries_id, entries_fp=entries_fp, db=db_f32,

@@ -96,6 +96,11 @@ _QUERY_CALLS = get_registry().counter(
 
 _INVALID = np.int32(2**31 - 1)
 
+# f32 products on every backend: a TPU's default f32 matmul is one bf16
+# pass, which would move query hashes across the float64 build's floor()
+# boundaries and perturb distances.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class QueryConfig:
@@ -192,7 +197,8 @@ class QueryResult:
 
 def _hash_queries(q, a_t, b_t, rm_t, wr, u, fp_bits):
     """[Q, d] -> bucket [Q, L] int32, fp [Q, L] uint32."""
-    proj = jnp.einsum("qd,lmd->qlm", q, a_t, preferred_element_type=jnp.float32)
+    proj = jnp.einsum("qd,lmd->qlm", q, a_t, precision=_HIGHEST,
+                      preferred_element_type=jnp.float32)
     hj = jnp.floor((proj + b_t[None] * wr) / wr).astype(jnp.int32)
     acc = jnp.sum(hj.astype(jnp.uint32) * rm_t[None].astype(jnp.uint32), axis=-1,
                   dtype=jnp.uint32)
@@ -265,7 +271,8 @@ def _probe_radius(ix: IndexArrays, queries, qnorm2, t, radius, cfg: QueryConfig,
     valid = buf_id != _INVALID
     safe_id = jnp.where(valid, buf_id, 0)
     coords = jnp.take(ix.db, safe_id, axis=0)                     # [Q, SBUF, d]
-    dot = jnp.einsum("qsd,qd->qs", coords, queries, preferred_element_type=jnp.float32)
+    dot = jnp.einsum("qsd,qd->qs", coords, queries, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32)
     xn2 = jnp.take(ix.db_norm2, safe_id, axis=0)
     d2 = xn2 - 2.0 * dot + qnorm2[:, None]
     d2 = jnp.where(valid, jnp.maximum(d2, 0.0), jnp.inf)
@@ -635,6 +642,8 @@ class SearchEngine:
             self._by_block_objs = {}
             return
         if hasattr(index, "num_shards"):      # ShardedIndexArrays
+            if mesh is not None:   # each shard straight to its own device
+                index = index.to_mesh(mesh, self.index_axes)
             self._sharded = index
             self._sharded_by_bo = {index.arrays.block_objs: index}
         else:                                  # E2LSHIndex
@@ -706,8 +715,10 @@ class SearchEngine:
         requested block size (ROADMAP "sharded block_objs knob")."""
         bo = int(block_objs or self._base_block_objs)
         if bo not in self._sharded_by_bo:
-            self._sharded_by_bo[bo] = (
-                self._sharded_by_bo[self._base_block_objs].with_block_objs(bo))
+            sh = self._sharded_by_bo[self._base_block_objs].with_block_objs(bo)
+            if self.mesh is not None:
+                sh = sh.to_mesh(self.mesh, self.index_axes)
+            self._sharded_by_bo[bo] = sh
         return self._sharded_by_bo[bo]
 
     def config(self, *, k: int = 1, collect_probe_sizes: bool = False,

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..dispatch import default_interpret, use_pallas_default
-from .kernel import bucket_probe_pallas
+from .kernel import TILE_G, bucket_probe_pallas
 from .ref import bucket_probe_ref, INVALID
 
 __all__ = ["bucket_probe", "blockify_entries", "INVALID"]
@@ -34,7 +34,9 @@ def blockify_entries(entries_id: np.ndarray, entries_fp: np.ndarray,
                      block_objs: int, *, lane_pad: int = 128):
     """Pack CSR entries into [NB, BLKp] block rows (BLKp = pad to `lane_pad`).
 
-    Returns (ids_blocks, fps_blocks, head_row, n_rows) where head_row has the
+    Returns host numpy (ids_blocks, fps_blocks, head_row, n_rows) — the
+    caller decides where the store lives (one device, or one shard per
+    device of a mesh) — where head_row has the
     same shape as table_off and holds the first block row of each bucket
     (-1 if empty); chains occupy consecutive rows (see core.index notes).
     Row 0 is a guaranteed-empty spare so callers can use it as safe padding.
@@ -73,8 +75,7 @@ def blockify_entries(entries_id: np.ndarray, entries_fp: np.ndarray,
         cols = local % block_objs
         ids_blocks[rows, cols] = entries_id[src]
         fps_blocks[rows, cols] = entries_fp[src]
-    return (jnp.asarray(ids_blocks), jnp.asarray(fps_blocks),
-            jnp.asarray(head.reshape(np.asarray(table_off).shape)), NB)
+    return ids_blocks, fps_blocks, head.reshape(np.asarray(table_off).shape), NB
 
 
 @partial(jax.jit, static_argnames=("interpret", "use_pallas"))
@@ -94,8 +95,10 @@ def bucket_probe(block_rows, qfp, ids_blocks, fps_blocks, *,
         interpret = default_interpret()
     if not use_pallas:
         return bucket_probe_ref(block_rows, qfp, ids_blocks, fps_blocks)
-    qfp2 = qfp.astype(jnp.int32).reshape(-1, 1)
-    return bucket_probe_pallas(
-        block_rows.astype(jnp.int32), qfp2, ids_blocks, fps_blocks,
-        interpret=interpret,
-    )
+    G = block_rows.shape[0]
+    pad = -G % TILE_G        # padding steps read spare row 0 (empty)
+    rows = jnp.pad(block_rows.astype(jnp.int32), (0, pad))
+    qfp2 = jnp.pad(qfp.astype(jnp.int32), (0, pad)).reshape(-1, 1)
+    out = bucket_probe_pallas(rows, qfp2, ids_blocks, fps_blocks,
+                              interpret=interpret)
+    return out[:G]

@@ -18,11 +18,15 @@ from jax.experimental import pallas as pl
 
 __all__ = ["l2_distance_pallas", "l2_distance_gathered_pallas"]
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))     # contract the minor dims: a @ b.T
+
 
 def _kernel(q_ref, x_ref, out_ref):
     q = q_ref[...]                                   # [TQ, D]
     x = x_ref[...]                                   # [TC, D]
-    dot = jnp.dot(q, x.T, preferred_element_type=jnp.float32)   # MXU [TQ, TC]
+    dot = jax.lax.dot_general(q, x, _NT, precision=_HIGHEST,
+                              preferred_element_type=jnp.float32)  # MXU [TQ, TC]
     qn2 = jnp.sum(q * q, axis=-1, keepdims=True)     # [TQ, 1]
     xn2 = jnp.sum(x * x, axis=-1, keepdims=True).T   # [1, TC]
     out_ref[...] = jnp.maximum(qn2 + xn2 - 2.0 * dot, 0.0)
@@ -44,41 +48,43 @@ def l2_distance_pallas(q, x, *, tile_q: int = 128, tile_c: int = 128,
         out_specs=pl.BlockSpec((tile_q, tile_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((NQ, NC), jnp.float32),
         interpret=interpret,
+        name="l2_distance",
     )(q, x)
 
 
 def _gathered_kernel(q_ref, coords_ref, xn2_ref, qn2_ref, out_ref):
     """One query per grid step: candidate matvec + norm-expansion epilogue.
 
-    The dominant term coords @ q^T is an [S, D] x [D, 1] MXU matvec in the
-    same VMEM residency as the epilogue; norms arrive precomputed (xn2 is the
-    DRAM-tier ||x||^2 cache, shared across radii), matching the oracle's
+    The query dim is squeezed out of every block, so the step sees its query
+    [1, D] against ITS candidates [Sp, D]; norms arrive precomputed (xn2 is
+    the DRAM-tier ||x||^2 cache, shared across radii), matching the oracle's
     d2 = xn2 - 2<x, q> + qn2 op order. Unclamped; callers mask + clamp.
     """
-    q = q_ref[...]                    # [1, D]
-    coords = coords_ref[0]            # [Sp, D]
-    xn2 = xn2_ref[...]                # [1, Sp]
-    qn2 = qn2_ref[...]                # [1, 1]
-    dot = jnp.dot(coords, q.T, preferred_element_type=jnp.float32)  # [Sp, 1]
-    out_ref[...] = xn2 - 2.0 * dot.T + qn2
+    dot = jax.lax.dot_general(q_ref[...], coords_ref[...], _NT,
+                              precision=_HIGHEST,
+                              preferred_element_type=jnp.float32)  # [1, Sp]
+    out_ref[...] = xn2_ref[...] - 2.0 * dot + qn2_ref[...]
 
 
 def l2_distance_gathered_pallas(q, coords, xn2, qn2, *, interpret: bool = False):
-    """q [Q, D], coords [Q, Sp, D], xn2 [Q, Sp], qn2 [Q, 1] -> d2 [Q, Sp].
+    """q [Q, 1, D], coords [Q, Sp, D], xn2 [Q, 1, Sp], qn2 [Q, 1, 1]
+    -> d2 [Q, 1, Sp].
 
-    D % 128 == 0 and Sp % 128 == 0 (ops.py pads); grid is one query per step.
+    D % 128 == 0 and Sp % 128 == 0 (ops.py pads); the unit dims keep each
+    block's last two dims equal to the array's.
     """
     Q, Sp, D = coords.shape
     return pl.pallas_call(
         _gathered_kernel,
         grid=(Q,),
         in_specs=[
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, Sp, D), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, Sp), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, D), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, Sp, D), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, Sp), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Sp), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Q, Sp), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, Sp), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Q, 1, Sp), jnp.float32),
         interpret=interpret,
+        name="l2_distance_gathered",
     )(q, coords, xn2, qn2)

@@ -58,10 +58,10 @@ def l2_distance_gathered(q, coords, xn2, qn2, *, interpret: bool = None,
         return l2_distance_gathered_ref(q, coords, xn2, qn2)
     Dp = _pad_to(max(D, 128), 128)
     Sp = _pad_to(max(S, 128), 128)
-    qp = jnp.zeros((Q, Dp), jnp.float32).at[:, :D].set(q.astype(jnp.float32))
-    cp = jnp.zeros((Q, Sp, Dp), jnp.float32).at[:, :S, :D].set(
-        coords.astype(jnp.float32))
-    xn2p = jnp.zeros((Q, Sp), jnp.float32).at[:, :S].set(xn2.astype(jnp.float32))
-    qn2p = qn2.astype(jnp.float32).reshape(Q, 1)
+    f32 = lambda v: v.astype(jnp.float32)
+    qp = jnp.pad(f32(q), ((0, 0), (0, Dp - D)))[:, None]
+    cp = jnp.pad(f32(coords), ((0, 0), (0, Sp - S), (0, Dp - D)))
+    xn2p = jnp.pad(f32(xn2), ((0, 0), (0, Sp - S)))[:, None]
+    qn2p = f32(qn2).reshape(Q, 1, 1)
     out = l2_distance_gathered_pallas(qp, cp, xn2p, qn2p, interpret=interpret)
-    return out[:, :S]
+    return out[:, 0, :S]

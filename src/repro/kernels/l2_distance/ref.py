@@ -1,6 +1,7 @@
 """Pure-jnp oracles for the l2_distance kernels."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["l2_distance_ref", "l2_distance_gathered_ref"]
@@ -10,7 +11,8 @@ def l2_distance_ref(q, x):
     """q [NQ, D], x [NC, D] -> d2 [NQ, NC] (clamped at 0)."""
     q = q.astype(jnp.float32)
     x = x.astype(jnp.float32)
-    dot = jnp.dot(q, x.T, preferred_element_type=jnp.float32)
+    dot = jnp.dot(q, x.T, precision=jax.lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)
     qn2 = jnp.sum(q * q, axis=-1, keepdims=True)
     xn2 = jnp.sum(x * x, axis=-1, keepdims=True).T
     return jnp.maximum(qn2 + xn2 - 2.0 * dot, 0.0)
@@ -24,5 +26,6 @@ def l2_distance_gathered_ref(q, coords, xn2, qn2):
     caller masks invalid slots and clamps, mirroring core.query's oracle).
     """
     dot = jnp.einsum("qsd,qd->qs", coords.astype(jnp.float32),
-                     q.astype(jnp.float32), preferred_element_type=jnp.float32)
+                     q.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
     return xn2 - 2.0 * dot + qn2[:, None]

@@ -1,6 +1,7 @@
 """Pure-jnp oracle for the lsh_hash kernel (mirrors core.hashing exactly)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["lsh_hash_ref", "lsh_hash_all_radii_ref"]
@@ -15,6 +16,7 @@ def lsh_hash_ref(x, a, b, rm, *, w_r: float, u: int, fp_bits: int):
     # repro.core at module-import time
     from ...core.hashing import fmix32
     proj = jnp.einsum("nd,lmd->nlm", x.astype(jnp.float32), a.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST,
                       preferred_element_type=jnp.float32)
     hj = jnp.floor((proj + b[None] * w_r) / w_r).astype(jnp.int32)
     acc = jnp.sum(hj.astype(jnp.uint32) * rm[None].astype(jnp.uint32), axis=-1,

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .mesh import available_mesh
+from ..compile_cache import enable_compile_cache
 from ..configs import get_config
 from ..core import E2LSHoS, SearchEngine, measured_query, overall_ratio
 from ..core.distributed import build_sharded_index
@@ -348,6 +348,7 @@ def main(argv=None):
                          "up (per query tree; 0 disables tracing but keeps "
                          "/metrics live)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     server = None
     if args.metrics_port is not None:
         if args.trace_sampling > 0:

@@ -10,7 +10,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from ..core.compat import shard_map
 from ..models.config import ArchConfig
 from ..models.model import Model
 from .optimizer import AdamWConfig, OptState, adamw_update, init_opt_state
@@ -136,9 +135,9 @@ def make_shardmap_dp_train_step(model: Model, opt_cfg: AdamWConfig, mesh,
     del rep
     state_spec = P()
     batch_spec = P(axis_name)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(state_spec, batch_spec),
-        out_specs=(state_spec, state_spec),
+        out_specs=(state_spec, state_spec), check_vma=False,
     )
     return jax.jit(fn)

@@ -142,6 +142,38 @@ def test_sharded_block_objs_reblockify_layout():
         engine.make_plan_fn(plan="sharded", max_chain=7)
 
 
+def test_sharded_index_places_each_shard_on_its_device():
+    """build_sharded_index keeps every leaf in host numpy; SearchEngine(...,
+    mesh=) places the stacked stores straight onto the mesh, one shard per
+    device (no device-0 staging), re-blockified layouts included."""
+    res = _run("""
+        import json
+        import numpy as np, jax
+        from jax.sharding import Mesh
+        from repro.core import SearchEngine
+        from repro.core.distributed import build_sharded_index
+        from repro.core.index import IndexArrays
+
+        db = np.random.default_rng(2).normal(size=(801, 8)).astype(np.float32)
+        sh = build_sharded_index(db, 4, gamma=0.7, max_L=4, seed=1)
+        on_host = all(isinstance(getattr(sh.arrays, f), np.ndarray)
+                      for f in IndexArrays.array_fields())
+        devs = jax.devices()[:4]
+        engine = SearchEngine(sh, mesh=Mesh(np.array(devs), ("shard",)))
+        out = {"on_host": on_host}
+        for bo in (None, 16):
+            ix = engine.arrays(block_objs=bo)
+            shards = ix.ids_blocks.addressable_shards
+            out[str(bo)] = (
+                sorted(s.device.id for s in shards) == [d.id for d in devs]
+                and all(s.data.shape[0] == 1 for s in shards)
+                and len(ix.a.addressable_shards) == 4
+                and ix.a.addressable_shards[0].data.shape == ix.a.shape)
+        print(json.dumps(out))
+    """)
+    assert res == {"on_host": True, "None": True, "16": True}
+
+
 def test_sharded_block_objs_reblockify_query_parity():
     """plan="sharded" over the re-blockified per-shard stores is bit-exact
     with the sharded oracle reading the (unchanged) CSR view under the same

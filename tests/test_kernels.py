@@ -148,3 +148,25 @@ def test_kernels_used_by_core_match_production_hash(built_index):
     np.testing.assert_array_equal(np.asarray(bk_prod), np.asarray(bk_k))
     np.testing.assert_array_equal(np.asarray(fp_prod),
                                   np.asarray(fp_k).astype(np.uint32))
+
+
+def test_lsh_hash_vmem_overflow_runs_reference_and_counts(monkeypatch):
+    """A projection block past the scoped-VMEM budget runs the jnp reference
+    (same answers) and shows on the reference-path counter, per op."""
+    from repro.kernels.lsh_hash import ops
+
+    monkeypatch.setattr(ops, "use_pallas_default", lambda: True)
+    r, L, m, d = 2, 64, 16, 1024       # a [1024, 16 * 128] block: 8 MiB
+    assert ops._vmem_bytes(d, r * L, m, 256) > ops._SCOPED_VMEM_BYTES
+    x = RNG.normal(size=(8, d)).astype(np.float32)
+    a = RNG.normal(size=(r, L, m, d)).astype(np.float32)
+    b = RNG.uniform(size=(r, L, m)).astype(np.float32)
+    rm = ((RNG.integers(1, 2**31, size=(r, L, m)).astype(np.uint32) << 1)
+          | 1).astype(np.int32)
+    kw = dict(w=4.0, radii=(1.0, 2.0), u=12, fp_bits=10)
+    before = ops.REFERENCE_CALLS.labels(op="lsh_hash_all_radii").value()
+    got = lsh_hash_all_radii(*map(jnp.asarray, (x, a, b, rm)), **kw)
+    want = lsh_hash_all_radii_ref(*map(jnp.asarray, (x, a, b, rm)), **kw)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert ops.REFERENCE_CALLS.labels(op="lsh_hash_all_radii").value() \
+        == before + 1
