@@ -40,6 +40,7 @@ deprecation-lane:
 	leaked += ['E2LSHIndex.as_arrays'] if hasattr(i.E2LSHIndex, 'as_arrays') else []; \
 	leaked += ['E2LSHoS.arrays'] if hasattr(c.E2LSHoS, 'arrays') else []; \
 	leaked += ['E2LSHoS.fused_arrays'] if hasattr(c.E2LSHoS, 'fused_arrays') else []; \
+	leaked += ['SearchEngine.last_external_stats'] if hasattr(c.SearchEngine, 'last_external_stats') else []; \
 	assert not leaked, f'deprecated names resurfaced: {leaked}'; \
 	print('deprecation lane OK: legacy wrapper names are gone')"
 

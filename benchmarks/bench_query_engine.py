@@ -670,7 +670,7 @@ def _check_telemetry_snapshot(snap: dict, where: str):
         for s in entry["samples"]:
             assert "labels" in s, f"{where}/{name}: sample without labels"
     for required in ("e2lsh_query_calls_total", "e2lsh_store_reads_total",
-                     "e2lsh_serve_ticks_total", "e2lsh_serve_dispatch_ms"):
+                     "e2lsh_serve_ticks_total", "e2lsh_serve_tick_phase_ms"):
         assert required in snap, f"{where}: missing series {required}"
 
 

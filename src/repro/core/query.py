@@ -71,7 +71,6 @@ The seed's free-function surface (`query_batch*`, `ensure_fused_arrays`,
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from functools import partial
 from typing import Optional
 
@@ -675,21 +674,6 @@ class SearchEngine:
         needs — last_plan_stats is overwritten per tick), and ``.store``
         (the live I/O ledger)."""
         return self._external
-
-    @property
-    def last_external_stats(self):
-        """Deprecated (one-PR window, telemetry PR): use
-        ``engine.external.last_plan_stats`` — or ``.plan_totals`` /
-        ``telemetry.snapshot()`` when accumulating across queued ticks,
-        which this overwritten-per-call surface silently cannot do."""
-        warnings.warn(
-            "SearchEngine.last_external_stats is deprecated: use "
-            "engine.external.last_plan_stats (per-call), "
-            "engine.external.plan_totals (accumulating), or "
-            "repro.telemetry.snapshot() (unified metrics)",
-            DeprecationWarning, stacklevel=2)
-        return (self._external.last_plan_stats
-                if self._external is not None else None)
 
     # -- typed array access -------------------------------------------------
     def arrays(self, block_objs: Optional[int] = None) -> IndexArrays:

@@ -57,8 +57,20 @@ class TickStats:
     segments: int        # request segments packed into the tick
     pad_rows: int        # masked padding rows (shape - rows)
     occupancy: float     # rows / shape
-    dispatch_ms: float   # wall time of the single fused dispatch
+    dispatch_ms: float   # launch_ms + wait_ms: the single fused dispatch
     shed: int = 0        # tickets shed (DeadlineExceeded) at this tick's pack
+    # host phases, ms on perf_counter; contiguous, so they sum to <= wall_ms
+    pack_ms: float = 0.0     # lock, pack, shed, pad to the rung
+    launch_ms: float = 0.0   # inputs to the device + plan call (enqueue)
+    wait_ms: float = 0.0     # block_until_ready: the host waits on the device
+    fetch_ms: float = 0.0    # device_get of the tick's result
+    deliver_ms: float = 0.0  # per-segment slices, ticket delivery, QoS records
+    wall_ms: float = 0.0     # the whole tick, from its start to its commit
+    queued_ms: float = 0.0   # mean over segments of pack time - submit time
+
+
+# the host phases of one tick, in order; each is a TickStats ``<phase>_ms``
+TICK_PHASES = ("pack", "launch", "wait", "fetch", "deliver")
 
 
 @dataclasses.dataclass
@@ -234,6 +246,10 @@ class BatchQueue:
         self.qos_log: list = []          # one dict per deadline/priority ticket
         self.shed_count = 0              # tickets shed with DeadlineExceeded
         self._warmed_at = -1             # dispatch_count at last cache warm
+        # registry cells bound once: one observe per phase per tick
+        self._phase_hist = {p: _TICK_PHASE_MS.labels(plan=self.plan, phase=p)
+                            for p in TICK_PHASES}
+        self._queued_hist = _QUEUED_MS.labels(plan=self.plan)
         _LIVE_QUEUES.add(self)           # telemetry collector (module foot)
         ext = self.engine.external
         if self.warm_cache_rows > 0 and ext is not None:
@@ -353,7 +369,10 @@ class BatchQueue:
                 root.end()
 
     def _tick_locked(self, tr, root) -> Optional[TickStats]:
-        """The tick body, under ``_serve_lock`` with its root span open."""
+        """The tick body, under ``_serve_lock`` with its root span open.
+        Each phase is one child span (recorded only while tracing is on)
+        and one ``perf_counter`` read at its end (always)."""
+        t_start = time.perf_counter()
         now = time.monotonic()
         urgent_s = 2.0 * self.tick_us * 1e-6   # slack beating shape reuse
         psp = tr.begin("tick.pack")
@@ -414,11 +433,6 @@ class BatchQueue:
                     1 for e in self._pending
                     if e.priority != 0 or e.deadline is not None)
         n_shed = len(shed_tickets)
-        if not batch and not n_shed:
-            psp.cancel()          # idle poll: keep the span ring quiet
-        else:
-            psp.set(segments=len(batch), rows=rows, shed=n_shed)
-            psp.end()
         for t in shed_tickets.values():
             with self._stats_lock:
                 self.shed_count += 1
@@ -428,7 +442,11 @@ class BatchQueue:
                 f"{(now - t.deadline) * 1e3:.1f}ms before its tick"))
             self._record_qos(t, now=now, shed=True)
         if not batch:
-            if not n_shed:
+            if n_shed:
+                psp.set(segments=0, rows=0, shed=n_shed)
+                psp.end()
+            else:
+                psp.cancel()      # idle poll: keep the span ring quiet
                 root.cancel()     # nothing happened; drop the empty tick
             return None
         shape = self.shape_for(rows)
@@ -436,11 +454,16 @@ class BatchQueue:
         qs[:rows] = np.concatenate([e.seg for e in batch], axis=0)
         valid = np.zeros((shape,), dtype=bool)
         valid[:rows] = True
-        t0 = time.perf_counter()
+        psp.set(segments=len(batch), rows=rows, shed=n_shed)
+        psp.end()
+        t_pack = time.perf_counter()
         try:
             with tr.span("tick.dispatch", shape=shape, rows=rows):
-                res = self._fn(jnp.asarray(qs), jnp.asarray(valid))
-                jax.block_until_ready(res.ids)
+                with tr.span("tick.launch"):
+                    res = self._fn(jnp.asarray(qs), jnp.asarray(valid))
+                t_launch = time.perf_counter()
+                with tr.span("tick.wait"):
+                    jax.block_until_ready(res.ids)
         except Exception as e:
             # the popped segments can never be re-served at this point:
             # fail their tickets (waiters raise instead of hanging) and
@@ -448,22 +471,35 @@ class BatchQueue:
             for p in batch:
                 p.ticket._fail(e)
             raise
-        dispatch_ms = (time.perf_counter() - t0) * 1e3
-        _DISPATCH_MS.observe(dispatch_ms, plan=self.plan)
+        t_wait = time.perf_counter()
         root.set(shape=shape, rows=rows, segments=len(batch))
         # ONE device->host transfer for the whole tick; the per-segment
         # scatter is then numpy views (per-segment device slicing costs
         # more than the dispatch itself at high request counts)
         with tr.span("tick.scatter", segments=len(batch)):
-            host = jax.device_get(res)
-            done_t = time.monotonic()
-            lo = 0
-            for p in batch:
-                hi = lo + p.seg.shape[0]
-                p.ticket._deliver(p.seg_idx, host.slice_rows(lo, hi))
-                lo = hi
-                if p.ticket.done():
-                    self._record_qos(p.ticket, now=done_t, shed=False)
+            with tr.span("tick.fetch"):
+                host = jax.device_get(res)
+            t_fetch = time.perf_counter()
+            with tr.span("tick.deliver"):
+                done_t = time.monotonic()
+                lo = 0
+                for p in batch:
+                    hi = lo + p.seg.shape[0]
+                    p.ticket._deliver(p.seg_idx, host.slice_rows(lo, hi))
+                    lo = hi
+                    if p.ticket.done():
+                        self._record_qos(p.ticket, now=done_t, shed=False)
+        t_end = time.perf_counter()
+        queued_ms = [(now - p.ticket.submit_t) * 1e3 for p in batch]
+        phase_ms = dict(pack=(t_pack - t_start) * 1e3,
+                        launch=(t_launch - t_pack) * 1e3,
+                        wait=(t_wait - t_launch) * 1e3,
+                        fetch=(t_fetch - t_wait) * 1e3,
+                        deliver=(t_end - t_fetch) * 1e3)
+        for phase, ms in phase_ms.items():
+            self._phase_hist[phase].observe(ms)
+        for ms in queued_ms:
+            self._queued_hist.observe(ms)
         # atomic stats commit: a concurrent stats_summary() can never
         # see the new dispatch count without its tick row (or vice versa)
         with self._stats_lock:
@@ -471,10 +507,14 @@ class BatchQueue:
             stats = TickStats(
                 tick=len(self.tick_log), shape=shape, rows=rows,
                 segments=len(batch), pad_rows=shape - rows,
-                occupancy=rows / shape, dispatch_ms=dispatch_ms,
-                shed=n_shed,
+                occupancy=rows / shape,
+                dispatch_ms=phase_ms["launch"] + phase_ms["wait"],
+                shed=n_shed, **{f"{k}_ms": v for k, v in phase_ms.items()},
+                wall_ms=(time.perf_counter() - t_start) * 1e3,
+                queued_ms=sum(queued_ms) / len(queued_ms),
             )
             self.tick_log.append(stats)
+        root.set(tick=stats.tick)
         return stats
 
     def drain(self) -> int:
@@ -554,8 +594,11 @@ class BatchQueue:
     # -- observability ------------------------------------------------------
     def stats_summary(self, window: Optional[int] = None) -> dict:
         """Aggregate tick stats: occupancy, pad waste, dispatch p50/p99,
-        the ladder-rung histogram, and the QoS block (shed counts +
-        deadline hit rates, overall and per priority class).
+        the ladder-rung histogram, the mean of each host phase
+        (``pack_ms`` ... ``deliver_ms``) and of the whole tick
+        (``tick_wall_ms``), queue wait from submit to pack p50/p99 (over
+        the ticks' means), and the QoS block (shed counts + deadline hit
+        rates, overall and per priority class).
 
         ``window=N`` restricts the tick aggregates to the last N ticks (the
         sliding view the adaptive packer sees); the default is cumulative.
@@ -584,6 +627,7 @@ class BatchQueue:
             out = dict(ticks=0, dispatches=dispatches, rows_served=0)
         else:
             dms = np.asarray([t.dispatch_ms for t in log])
+            qms = np.asarray([t.queued_ms for t in log])
             slots = sum(t.shape for t in log)
             rows = sum(t.rows for t in log)
             rung_hist = {int(s): 0 for s in self.ladder}
@@ -599,7 +643,13 @@ class BatchQueue:
                 p50_dispatch_ms=float(np.percentile(dms, 50)),
                 p99_dispatch_ms=float(np.percentile(dms, 99)),
                 rung_hist=rung_hist,
+                tick_wall_ms=float(np.mean([t.wall_ms for t in log])),
+                p50_queued_ms=float(np.percentile(qms, 50)),
+                p99_queued_ms=float(np.percentile(qms, 99)),
             )
+            for p in TICK_PHASES:
+                out[f"{p}_ms"] = float(np.mean(
+                    [getattr(t, f"{p}_ms") for t in log]))
         out["qos"] = self._qos_summary(qlog, shed)
         ext = self.engine.external
         if ext is not None:
@@ -664,9 +714,14 @@ class BatchQueue:
 # series, Prometheus-style). Queues are weakly held: a gc'd queue's series
 # disappear, which the registry's baseline clamp tolerates.
 _LIVE_QUEUES: "weakref.WeakSet[BatchQueue]" = weakref.WeakSet()
-_DISPATCH_MS = get_registry().histogram(
-    "e2lsh_serve_dispatch_ms",
-    "wall time of one fused tick dispatch (ms)", labelnames=("plan",))
+_TICK_PHASE_MS = get_registry().histogram(
+    "e2lsh_serve_tick_phase_ms",
+    "host time of one tick phase (ms): pack, launch, wait, fetch, deliver",
+    labelnames=("plan", "phase"))
+_QUEUED_MS = get_registry().histogram(
+    "e2lsh_serve_queued_ms",
+    "wait of one request segment from submit to its tick's pack (ms)",
+    labelnames=("plan",))
 
 
 def _collect_queue_metrics() -> dict:

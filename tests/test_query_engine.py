@@ -190,7 +190,7 @@ def test_legacy_wrapper_surface_is_gone(built_index):
     import repro.core as core
     import repro.core.distributed as dist
     import repro.core.query as query
-    from repro.core import E2LSHoS
+    from repro.core import E2LSHoS, SearchEngine
     from repro.core.index import E2LSHIndex, IndexArrays
 
     for name in ("query_batch", "query_batch_fused", "query_batch_adaptive",
@@ -203,7 +203,8 @@ def test_legacy_wrapper_surface_is_gone(built_index):
     assert "sharded_query" not in dist.__all__
     for cls, name in ((IndexArrays, "from_dict"), (IndexArrays, "as_dict"),
                       (E2LSHIndex, "as_arrays"), (E2LSHoS, "arrays"),
-                      (E2LSHoS, "fused_arrays")):
+                      (E2LSHoS, "fused_arrays"),
+                      (SearchEngine, "last_external_stats")):
         assert not hasattr(cls, name), f"{cls.__name__}.{name} resurfaced"
     # the typed field (NOT the deleted dict accessor) is still the index API
     assert isinstance(built_index.index.arrays, IndexArrays)

@@ -22,6 +22,7 @@ except ImportError:
 
 from repro.core import E2LSHoS, SearchEngine
 from repro.serving import BatchQueue, TickStats
+from repro.serving.engine import TICK_PHASES
 
 _EXACT_FIELDS = ("ids", "dists", "found", "radii_searched", "nio_table",
                  "nio_blocks", "cands_checked")
@@ -203,6 +204,44 @@ def test_stats_summary_accounting(queue_env):
     assert 0.0 < s["occupancy_mean"] <= 1.0
     assert 0.0 <= s["pad_waste"] < 1.0
     assert s["p99_dispatch_ms"] >= s["p50_dispatch_ms"] > 0.0
+
+
+def test_tick_phase_counters_cover_the_tick(queue_env):
+    """Each tick times its host phases back to back: every phase is
+    positive, dispatch_ms is launch + wait, the phases fit inside the
+    tick's wall time, and stats_summary() carries their window means and
+    the queue wait from submit to pack."""
+    queue = _fresh_queue(queue_env)
+    for b in (3, 2, 9, 5):
+        queue.submit(queue_env["make_request"](b))
+    queue.drain()
+    assert len(queue.tick_log) >= 2
+    for t in queue.tick_log:
+        phases = [getattr(t, f"{p}_ms") for p in TICK_PHASES]
+        assert all(v > 0.0 for v in phases), phases
+        assert t.dispatch_ms == pytest.approx(t.launch_ms + t.wait_ms)
+        assert sum(phases) <= t.wall_ms
+        assert t.queued_ms > 0.0
+    s = queue.stats_summary()
+    for p in TICK_PHASES:
+        want = np.mean([getattr(t, f"{p}_ms") for t in queue.tick_log])
+        assert s[f"{p}_ms"] == pytest.approx(want)
+    assert s["tick_wall_ms"] == pytest.approx(
+        np.mean([t.wall_ms for t in queue.tick_log]))
+    assert s["tick_wall_ms"] >= sum(s[f"{p}_ms"] for p in TICK_PHASES)
+    assert s["p99_queued_ms"] >= s["p50_queued_ms"] > 0.0
+
+
+def test_queued_ms_counts_from_submit_to_pack(queue_env):
+    """A segment that waits for a later tick reads a longer queue wait."""
+    import time
+    queue = _fresh_queue(queue_env)
+    queue.submit(queue_env["make_request"](MAX_BATCH))
+    queue.submit(queue_env["make_request"](MAX_BATCH))   # spills a tick
+    time.sleep(0.02)
+    first, second = queue.tick(), queue.tick()
+    assert first.queued_ms >= 20.0
+    assert second.queued_ms >= first.queued_ms + first.wall_ms - 1.0
 
 
 def test_failed_dispatch_fails_tickets_not_hangs(queue_env):

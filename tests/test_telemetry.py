@@ -22,6 +22,7 @@ from repro import telemetry
 from repro.core import E2LSHoS, SearchEngine
 from repro.core.io_count import nio_for_block_size
 from repro.serving import BatchQueue
+from repro.serving.engine import TICK_PHASES
 from repro.telemetry import (MetricsServer, NOOP_SPAN, Registry, Tracer,
                              render_prometheus, spans_to_chrome)
 
@@ -442,7 +443,7 @@ def test_store_collector_in_unified_snapshot(storage_index, spilled):
 
 
 # --------------------------------------------------------------------------
-# Serving-tier integration: stats races, deprecation, live /metrics
+# Serving-tier integration: tick phase spans, stats races, live /metrics
 # --------------------------------------------------------------------------
 
 def test_plan_totals_accumulate_across_calls(storage_index, spilled):
@@ -461,14 +462,53 @@ def test_plan_totals_accumulate_across_calls(storage_index, spilled):
     assert delta.nio_blocks == first + ext.last_plan_stats.io.reads
 
 
-def test_last_external_stats_deprecated(storage_index, spilled):
+_TICK_TREE = {"tick.pack": "serve.tick", "tick.dispatch": "serve.tick",
+              "tick.scatter": "serve.tick", "tick.launch": "tick.dispatch",
+              "tick.wait": "tick.dispatch", "tick.fetch": "tick.scatter",
+              "tick.deliver": "tick.scatter"}
+
+
+def test_served_tick_records_its_phase_tree(storage_index):
+    """With tracing on, one served tick records serve.tick (carrying its
+    TickStats id) with pack / dispatch (launch, wait) / scatter (fetch,
+    deliver) nested in order; with tracing off it records nothing, and
+    the answers are bit-exact with the traced tick's."""
     idx, qs = storage_index
-    with st.load_external(spilled, backend="mem") as ext:
-        engine = SearchEngine(ext)
-        engine.query(qs[:4], k=1)
-        with pytest.warns(DeprecationWarning, match="last_external_stats"):
-            ps = engine.last_external_stats
-        assert ps is engine.external.last_plan_stats
+    q = BatchQueue(SearchEngine(idx), plan="fused", ladder=(8,), k=2)
+    q.submit(qs[:5])
+    q.tick()                                 # a first tick, not recorded
+    telemetry.enable(sampling=1.0)
+    tr = telemetry.get_tracer()
+    tr.clear()
+    on = q.submit(qs[5:10])
+    stats = q.tick()
+    spans = tr.drain()
+    by_name = {s.name: s for s in spans}
+    assert sorted(by_name) == sorted(["serve.tick", *_TICK_TREE])
+    assert len(spans) == len(by_name)        # one of each
+    root = by_name["serve.tick"]
+    assert root.parent is None and root.attrs["tick"] == stats.tick == 1
+    for child, parent in _TICK_TREE.items():
+        sp = by_name[child]
+        assert sp.parent == by_name[parent].sid, child
+        assert sp.ts_ns >= by_name[parent].ts_ns
+        assert sp.ts_ns + sp.dur_ns <= by_name[parent].ts_ns + \
+            by_name[parent].dur_ns
+    order = ["tick.pack", "tick.launch", "tick.wait", "tick.fetch",
+             "tick.deliver"]
+    ends = [by_name[n].ts_ns + by_name[n].dur_ns for n in order]
+    starts = [by_name[n].ts_ns for n in order]
+    assert all(e <= s for e, s in zip(ends, starts[1:]))
+
+    telemetry.disable()
+    off = q.submit(qs[5:10])
+    q.tick()
+    assert len(tr) == 0
+    a, b = on.result(timeout=0), off.result(timeout=0)
+    for name in ("ids", "dists", "found", "nio_blocks", "radii_searched",
+                 "nio_table", "cands_checked"):
+        np.testing.assert_array_equal(np.asarray(getattr(a, name)),
+                                      np.asarray(getattr(b, name)))
 
 
 def test_stats_summary_window_vs_reset_race(storage_index):
@@ -564,8 +604,10 @@ def test_live_metrics_server_under_load(storage_index, spilled):
             hit = series("e2lsh_serve_deadline_hit_rate{")
             assert hit and all(v == 1.0 for v in hit.values()), \
                 f"60s deadlines should all hit: {hit}"
-            assert sum(
-                series("e2lsh_serve_dispatch_ms_count").values()) >= 2
+            for p in TICK_PHASES:
+                assert metrics[f'e2lsh_serve_tick_phase_ms_count{{phase="{p}"'
+                               f',plan="external"}}'] >= 2, p
+            assert metrics['e2lsh_serve_queued_ms_count{plan="external"}'] == 4
 
             # /trace serves the same chrome-trace doc the exporter writes
             doc = json.loads(get("/trace?last=64"))
