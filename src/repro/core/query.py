@@ -219,6 +219,39 @@ def _append_candidates(buf_id, count, flat_id, flat_ok, S, SBUF):
     return buf_id, count
 
 
+def _compact_candidates(row_id, row_ok, S, SBUF):
+    """Select and gather the first S matches into a fresh candidate buffer.
+
+    `row_id`/`row_ok` [Q, G, W] are G block rows of W slots each, in the
+    buffer's flat (chain step, table, slot) order. Returns exactly what
+    `_append_candidates` returns for the flattened arrays and an empty
+    buffer, without its scatter (which a TPU runs update by update over all
+    Q*G*W flags, dropped or kept): match j sits in the first row whose
+    running match count passes j, at the first slot of that row whose
+    running count passes j's rank in the row. Flat `jnp.take` gathers read
+    that row's running counts and then the id (`take_along_axis` lowers to
+    a slower gather on a TPU).
+    """
+    Q, G, W = row_id.shape
+    slot_csum = jnp.cumsum(row_ok.astype(jnp.int32), axis=2)     # [Q, G, W]
+    row_cnt = slot_csum[:, :, -1]
+    row_csum = jnp.cumsum(row_cnt, axis=1)                       # [Q, G]
+    total = row_csum[:, -1]
+    j = jnp.arange(S, dtype=jnp.int32)
+    # the rows wholly before match j are those whose running count is <= j
+    before = row_csum[:, None, :] <= j[None, :, None]            # [Q, S, G]
+    rank = j[None, :] - jnp.sum(jnp.where(before, row_cnt[:, None, :], 0),
+                                axis=2)
+    row = (jnp.arange(Q, dtype=jnp.int32)[:, None] * G
+           + jnp.minimum(jnp.sum(before, axis=2, dtype=jnp.int32), G - 1))
+    in_row = jnp.take(slot_csum.reshape(Q * G, W), row, axis=0)  # [Q, S, W]
+    slot = jnp.sum(in_row <= rank[:, :, None], axis=2, dtype=jnp.int32)
+    got = jnp.take(row_id.reshape(-1), row * W + jnp.minimum(slot, W - 1))
+    got = jnp.where(j[None, :] < total[:, None], got, _INVALID)
+    buf_id = jnp.pad(got, ((0, 0), (0, SBUF - S)), constant_values=_INVALID)
+    return buf_id, jnp.minimum(total, S)
+
+
 def _probe_radius(ix: IndexArrays, queries, qnorm2, t, radius, cfg: QueryConfig,
                   active_q):
     """One (R, c)-NN probe for every query in the batch (ORACLE path).
@@ -333,11 +366,9 @@ def _probe_radius_fused(ix: IndexArrays, queries, qnorm2, cnt, head, qfp,
     blocks_read = jnp.sum(readable & step_active[:, :, None], axis=(1, 2),
                           dtype=jnp.int32)
 
-    buf_id = jnp.full((Q, SBUF), _INVALID, dtype=jnp.int32)
     flat_ok = (match != _INVALID) & step_active[:, :, None]
-    buf_id, count = _append_candidates(
-        buf_id, jnp.zeros((Q,), dtype=jnp.int32),
-        match.reshape(Q, C * L * BLKp), flat_ok.reshape(Q, C * L * BLKp),
+    buf_id, count = _compact_candidates(
+        match.reshape(Q, C * L, BLKp), flat_ok.reshape(Q, C * L, BLKp),
         S, SBUF)
 
     # distance check (Step 3) against the DRAM-tier coordinates

@@ -228,3 +228,38 @@ def test_masked_query_rows_are_inert(engine, clustered_data):
         assert np.isinf(np.asarray(tail.dists)).all()
         assert not np.asarray(tail.found).any()
         assert (np.asarray(tail.nio) == 0).all()
+
+
+# (Q, G block rows, W slots per row, S, SBUF): the HBM cell's fused probe
+# (128 queries, 2 chain steps x 32 tables, 128 lanes, S=64 in a 128-lane
+# buffer) and an off-TPU shape whose buffer is exactly S wide (_fused_sbuf)
+_COMPACT_SHAPES = {"cell": (128, 64, 128, 64, 128), "sbuf_eq_s": (16, 12, 8, 16, 16)}
+
+
+@pytest.mark.parametrize("density", ["none", "sparse", "about_s", "dense"])
+@pytest.mark.parametrize("shape", sorted(_COMPACT_SHAPES))
+def test_compact_candidates_matches_scatter_append(shape, density):
+    """The fused plan's gather compaction fills the candidate buffer and
+    count element for element as the oracle's scatter append does from an
+    empty buffer: the first S matches per row in flat (row, slot) order."""
+    from repro.core.query import _INVALID, _append_candidates, _compact_candidates
+
+    Q, G, W, S, SBUF = _COMPACT_SHAPES[shape]
+    N = G * W
+    rng = np.random.default_rng([Q, N, len(density)])
+    p = {"none": 0.0, "sparse": 0.001, "about_s": S / N, "dense": 0.5}[density]
+    ok = rng.random((Q, N)) < p
+    if density != "none":
+        ok[:4] = False                                  # row 0 keeps none
+        ok[1, rng.choice(N, S, replace=False)] = True   # exactly S
+        ok[2, rng.choice(N, 2 * S, replace=False)] = True   # more than S
+        ok[3, -1] = True                                # only the last slot
+    ids = rng.integers(0, 2**30, (Q, N), dtype=np.int32)
+    want_id, want_n = jax.jit(_append_candidates, static_argnums=(4, 5))(
+        jnp.full((Q, SBUF), _INVALID, jnp.int32), jnp.zeros((Q,), jnp.int32),
+        ids, ok, S, SBUF)
+    got_id, got_n = jax.jit(_compact_candidates, static_argnums=(2, 3))(
+        ids.reshape(Q, G, W), ok.reshape(Q, G, W), S, SBUF)
+    assert got_id.shape == (Q, SBUF)
+    np.testing.assert_array_equal(np.asarray(got_id), np.asarray(want_id))
+    np.testing.assert_array_equal(np.asarray(got_n), np.asarray(want_n))

@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.probabilities import solve_params
-from repro.core.query import QueryConfig
+from repro.core.query import QueryConfig, _append_candidates, _compact_candidates
 from repro.kernels.bucket_probe.ops import bucket_probe
 from repro.kernels.l2_distance.ops import l2_distance_gathered
 from repro.kernels.lsh_hash import lsh_hash_all_radii, lsh_hash_all_radii_ref
@@ -118,3 +118,25 @@ def test_query_hashing_lowers_at_f32_precision(one_chip):
     n_dots = oracle_hlo.count("stablehlo.dot_general")
     assert n_dots == PARAMS.r
     assert oracle_hlo.count("precision = [HIGHEST, HIGHEST]") == n_dots
+
+
+def test_candidate_compaction_compiles_without_scatter(one_chip):
+    """The fused probe's candidate compaction at the HBM cell's shape (128
+    queries, 2 chain steps x 32 tables of 128-lane block rows) lowers to no
+    scatter: a TPU scatter runs every one of the 1M updates, kept or not.
+    The oracle's scatter append, lowered the same way, shows the check sees
+    one."""
+    Qc, G, W = 128, CFG.max_chain * CFG.L, 128
+    rows = (_shape(one_chip, (Qc, G, W), jnp.int32),
+            _shape(one_chip, (Qc, G, W), jnp.bool_))
+    fn = lambda i, ok: _compact_candidates(i, ok, CFG.S, CFG.sbuf)
+    lowered = jax.jit(fn).lower(*rows)
+    assert "stablehlo.scatter" not in lowered.as_text()
+    assert " scatter(" not in lowered.compile().as_text()
+
+    flat = (_shape(one_chip, (Qc, CFG.sbuf), jnp.int32),
+            _shape(one_chip, (Qc,), jnp.int32),
+            _shape(one_chip, (Qc, G * W), jnp.int32),
+            _shape(one_chip, (Qc, G * W), jnp.bool_))
+    ref = lambda b, n, i, ok: _append_candidates(b, n, i, ok, CFG.S, CFG.sbuf)
+    assert "stablehlo.scatter" in jax.jit(ref).lower(*flat).as_text()
