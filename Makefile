@@ -82,9 +82,9 @@ telemetry-lane:
 # async-engine lane: force EVERY make_store call onto the uring backend
 # (REPRO_STORE_BACKEND — the storage twin of REPRO_FORCE_PALLAS) and run
 # the full parity suite + the N_io tie-out through it. The capability
-# probe gates the lane: where io_uring can't run (old kernel, seccomp)
-# the lane prints the probe's reason and skips instead of testing the
-# fallback twice.
+# probe gates the lane: where neither io_uring (old kernel, seccomp) nor
+# O_DIRECT works, the lane prints the probe's reason and skips instead of
+# testing the fallback twice.
 uring-lane:
 	@$(PY) -c "from repro.storage import capabilities; import json, sys; \
 	caps = capabilities(); print('capabilities:', json.dumps(caps)); \
@@ -94,5 +94,5 @@ uring-lane:
 		tests/test_storage_external.py \
 		tests/test_io_count.py::test_external_plan_measured_nio_matches_replay -q; \
 	elif [ $$rc -eq 3 ]; then \
-		echo "uring-lane SKIPPED: io_uring unavailable here (reason above)"; \
+		echo "uring-lane SKIPPED: no io_uring or O_DIRECT here (reason above)"; \
 	else exit $$rc; fi

@@ -318,8 +318,12 @@ def run_one_chip(n: int, phases: list) -> None:
     def external():
         caps = capabilities(str(ROOT))
         backend = "uring" if caps["uring_store"] else "aio"
-        why = ("io_uring works here" if caps["uring_store"]
-               else f"io_uring unavailable: {caps['io_uring_reason']}")
+        if caps["io_uring"]:
+            why = "io_uring works here"
+        elif caps["uring_store"]:
+            why = f"no io_uring; O_DIRECT reads at {caps['o_direct_align']} B"
+        else:
+            why = f"io_uring unavailable: {caps['io_uring_reason']}"
         log(f"[external] capabilities {json.dumps(caps)}")
         log(f"[external] backend={backend} ({why}); opened strict")
         with tempfile.TemporaryDirectory(dir=ROOT,

@@ -165,7 +165,7 @@ def serve_ann_external(args, ds):
             st0 = ext.store.shards[0] if shards > 1 else ext.store
             mode = "O_DIRECT" if st0.o_direct else "buffered"
             print(f"[external] uring engine up: qd={st0.qd}, {mode} "
-                  f"(align={st0.align})")
+                  f"(align={st0.align}), submit={st0.submit}")
         if args.queue:
             serve_ann_queued(args, engine, ds.queries, ds.gt_dists, plan=plan)
             s = ext.store.stats

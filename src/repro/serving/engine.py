@@ -656,6 +656,7 @@ class BatchQueue:
             store = ext.store
             es = store.stats.as_dict()
             es["backend"] = store.name
+            es["submit"] = getattr(store, "submit", None)
             es["fallback_from"] = getattr(store, "fallback_from", None)
             es["fallback_reason"] = getattr(store, "fallback_reason", None)
             shards = getattr(store, "num_shards", None)

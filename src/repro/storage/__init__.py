@@ -8,9 +8,10 @@ tables); this package holds the EXECUTABLE storage tier it predicts:
 * ``blockstore`` — pluggable block-read backends
   (``mem``/``mmap``/``aio``/``uring``) with the measured-N_io ledger and
   the shared clock page cache (``REPRO_STORE_BACKEND`` forces a lane);
-* ``uring``      — the real async I/O engine: io_uring wave submission +
-  O_DIRECT aligned reads via ctypes, with the runtime
-  ``capabilities()`` probe that gates it;
+* ``uring``      — the real async I/O engine: O_DIRECT aligned reads at
+  queue depth, submitted as io_uring waves via ctypes or, without
+  io_uring, from a ``preadv`` pool, with the runtime ``capabilities()``
+  probe that gates it;
 * ``external``   — ``plan="external"``: device hash/plan + host block
   fetches + device distance epilogue, with per-rung overlap stats;
 * ``measure``    — the measured sync-vs-async harness (cold-cache

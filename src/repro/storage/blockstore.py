@@ -15,13 +15,14 @@ disciplines the paper compares:
   a ``qd``-wide pread thread pool (paper Table 3 / Fig. 11's QD128 lane —
   T_async of Eq. 7 — approximated with one syscall per read through the
   page cache). Portable everywhere.
-* ``uring`` — the real thing (:mod:`repro.storage.uring`): misses are
-  submitted to the kernel through io_uring in waves of ``qd`` — one
-  syscall per wave, ``qd`` reads in flight at the device — with O_DIRECT
-  file access where the filesystem allows it, so demand reads bypass the
-  page cache and measured latency is device latency. Gated by
+* ``uring`` — the real thing (:mod:`repro.storage.uring`): O_DIRECT file
+  access where the filesystem allows it, so demand reads bypass the page
+  cache and measured latency is device latency, with ``qd`` reads in
+  flight: submitted to the kernel through io_uring in waves of ``qd`` (one
+  syscall per wave), or, where the kernel has no io_uring, as ``qd``
+  concurrent ``preadv`` calls. Gated by
   :func:`repro.storage.uring.capabilities`; ``make_store`` falls back to
-  ``aio`` when the kernel or filesystem can't do it.
+  ``aio`` only where neither io_uring nor O_DIRECT works.
 
 ``aio`` and ``uring`` share one cache/accounting engine
 (:class:`CachedBlockStore`) and differ ONLY in how a miss batch reaches
@@ -30,7 +31,8 @@ comparison isolates. Every backend counts the same ledger
 (:class:`StoreStats`): ``reads`` is the *logical* block-read count — the
 measured N_io the Eq. 6/7 validation compares against
 ``io_count.replay_probe_trace`` — while ``device_reads``/``cache_hits``/
-``prefetch_reads`` describe where those reads were served. Duplicate rows
+``prefetch_reads`` describe where those reads were served, and ``read_us``
+how long the reads issued to the file took. Duplicate rows
 inside one batch coalesce in the cache (counted as hits): that is
 precisely the page-cache effect the paper's mmap discussion describes,
 and it never changes the logical count.
@@ -47,6 +49,7 @@ import dataclasses
 import mmap
 import os
 import threading
+import time
 import weakref
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
@@ -87,6 +90,8 @@ class StoreStats:
     cache_hits: int = 0       # reads served from the page cache
     prefetch_reads: int = 0   # speculative reads issued by prefetch()
     read_batches: int = 0     # read_rows() calls
+    read_us: float = 0.0      # summed latency of the reads issued to the
+                              # file, demand and prefetch (aio, uring)
 
     @property
     def hit_rate(self) -> float:
@@ -143,11 +148,13 @@ class BlockStore:
             return self._read_rows_impl(rows)
         st = self.stats
         r0, h0, d0 = st.reads, st.cache_hits, st.device_reads
+        u0 = st.read_us
         with tr.span("store.read", backend=self.name,
                      **self.telemetry_labels) as sp:
             out = self._read_rows_impl(rows)
             sp.set(rows=st.reads - r0, cache_hits=st.cache_hits - h0,
-                   device_reads=st.device_reads - d0)
+                   device_reads=st.device_reads - d0,
+                   read_us=st.read_us - u0)
         return out
 
     def prefetch(self, rows: np.ndarray) -> None:
@@ -182,6 +189,8 @@ _STORE_COUNTER_HELP = {
     "cache_hits": "reads served from the store page cache",
     "prefetch_reads": "speculative reads on the prefetch lane",
     "read_batches": "read_rows() batches",
+    "read_us": "microseconds spent in reads issued to the file "
+               "(demand and prefetch)",
 }
 
 
@@ -345,6 +354,11 @@ class CachedBlockStore(BlockStore):
         per pool worker, up to ``qd``)."""
         return np.array_split(rows, min(self.qd, rows.size))
 
+    def _charge_read_ns(self, ns: int) -> None:
+        """Add device-read time (called from the pool's workers)."""
+        with self._lock:
+            self.stats.read_us += ns / 1e3
+
     def _fan_out(self, rows: np.ndarray) -> list:
         return [self._pool.submit(self._read_chunk, c)
                 for c in self._device_chunks(rows)]
@@ -479,13 +493,16 @@ class AioBlockStore(CachedBlockStore):
         super().__init__(nb, blkp, qd=qd, cache_rows=cache_rows)
 
     def _read_chunk(self, rows: np.ndarray) -> dict:
-        out = {}
+        out, spent_ns = {}, 0
         for g in rows:
+            t0 = time.perf_counter_ns()
             buf = os.pread(self._fd, self._stride,
                            self._base + int(g) * self._stride)
+            spent_ns += time.perf_counter_ns() - t0
             if len(buf) != self._stride:
                 raise IOError(f"short read at block row {int(g)}")
             out[int(g)] = np.frombuffer(buf, np.int32).reshape(2, self.blkp)
+        self._charge_read_ns(spent_ns)
         return out
 
     def close(self):
@@ -501,15 +518,18 @@ def make_store(backend: str, path, hdr, *, qd: int = 16,
     """Build a backend over a spilled file's ``blocks`` section.
 
     ``backend="uring"`` goes through the runtime capability probe
-    (:func:`repro.storage.uring.capabilities`) and falls back to the
-    ``aio`` thread pool when io_uring is unavailable (same contract, so
-    every caller keeps working); the returned store then carries
-    ``fallback_from="uring"`` and a ``fallback_reason``. ``strict=True``
-    raises instead (measurement lanes must not silently measure the
-    emulation). ``direct=False`` keeps the uring backend on buffered reads
-    even where O_DIRECT would work (see docs/storage.md on cache-defeating
-    measurement). ``REPRO_STORE_BACKEND`` overrides ``backend`` for every
-    call (see module docstring).
+    (:func:`repro.storage.uring.capabilities`): it returns the ``uring``
+    store wherever io_uring or O_DIRECT works (its ``submit`` says which
+    path it took). Only where neither works — the store would read through
+    the page cache without a queue — does it fall back to the ``aio``
+    thread pool (same contract, so every caller keeps working); the
+    returned store then carries ``fallback_from="uring"`` and a
+    ``fallback_reason``. ``strict=True`` raises instead, so a measurement
+    lane never measures the page cache unannounced. ``direct=False`` keeps
+    the uring backend on buffered reads even where O_DIRECT would work (see
+    docs/storage.md on cache-defeating measurement), which needs io_uring.
+    ``REPRO_STORE_BACKEND`` overrides ``backend`` for every call (see
+    module docstring).
     """
     if backend not in BACKENDS:
         # validate the caller's choice BEFORE the env override so a typo'd

@@ -315,9 +315,11 @@ def load_external(path, *, backend: str = "aio", qd: int = 16,
     :class:`~repro.storage.blockstore.BlockStore` backend (``mem`` — the
     in-memory parity oracle; ``mmap`` — synchronous QD1 page-cache reads;
     ``aio`` — ``qd``-way pread fan-out with a clock page cache of
-    ``cache_rows`` block rows; ``uring`` — io_uring wave submission with
-    O_DIRECT demand reads where supported, falling back to ``aio`` unless
-    ``strict``). ``direct=False`` keeps uring on buffered reads;
+    ``cache_rows`` block rows; ``uring`` — O_DIRECT demand reads with
+    ``qd`` in flight, submitted through io_uring or, without it, a
+    ``qd``-wide ``preadv`` pool; only where neither io_uring nor O_DIRECT
+    works does it fall back to ``aio``, or raise under ``strict``).
+    ``direct=False`` keeps uring on buffered reads through the ring;
     ``prefetch_depth`` is how many chain steps of the next rung the
     external plan pushes into the store's queue under device compute.
     Returns an :class:`~repro.storage.external.ExternalIndex` that

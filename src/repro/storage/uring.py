@@ -1,25 +1,35 @@
-"""Real asynchronous block I/O: io_uring + O_DIRECT, bound via ctypes.
+"""Real asynchronous block I/O: O_DIRECT demand reads at queue depth ``qd``,
+submitted through io_uring (bound via ctypes) or a ``preadv`` pool.
 
 PR 4's ``aio`` backend *emulates* high queue depth with a ``pread`` thread
 pool — useful as a portable discipline comparison, but every read still
 goes through the page cache and a full syscall round-trip per block, so on
 a cached spill it measures request handling, not the device (ROADMAP item
-1). This module is the paper's actual design (Sec. 5.2, Table 3): block
-reads are submitted to the kernel as a *batch* through an io_uring
-submission queue — one ``io_uring_enter`` syscall per wave of ``qd``
-reads — and the file is opened ``O_DIRECT`` so demand reads bypass the
-page cache and hit the device. No new dependency: the three io_uring
+1). This module is the paper's actual design (Sec. 5.2, Table 3): the file
+is opened ``O_DIRECT`` so demand reads bypass the page cache and hit the
+device, with ``qd`` reads in flight. Where the kernel has io_uring, a miss
+batch is submitted through its submission queue — one ``io_uring_enter``
+syscall per wave of ``qd`` reads. No new dependency: the three io_uring
 syscalls are raw ``libc.syscall`` calls and the shared rings are mapped
 with ``mmap`` — the same binding surface liburing wraps.
 
 Capability story: io_uring may be unavailable (old kernel, seccomp —
 ``ENOSYS``/``EPERM``) and ``O_DIRECT`` is per-filesystem (tmpfs refuses
-it). :func:`capabilities` probes both at runtime;
-:func:`~repro.storage.blockstore.make_store` uses it to fall back
-gracefully to the ``aio`` thread pool (same ``BlockStore`` contract, so
-``plan="external"``, ``BatchQueue`` and the N_io tie-out are unaffected —
-only the I/O discipline changes). A ``uring`` store without ``O_DIRECT``
-support still batches submissions; it just reads through the cache.
+it). :func:`capabilities` probes both at runtime. The ``uring`` store
+keeps its contract — demand reads that bypass the page cache, ``qd`` in
+flight — wherever either works:
+
+* io_uring works: the ring path (``submit == "io_uring"``), O_DIRECT where
+  the filesystem takes it, buffered reads through the ring otherwise;
+* no io_uring, but O_DIRECT works: ``qd`` pool workers each ``preadv``
+  their rows' aligned extents into their own landing slot
+  (``submit == "pread"``), threads standing in for the ring.
+
+Only where neither works would the store read through the page cache
+without a queue; there it refuses (:class:`UringUnavailable`), and
+:func:`~repro.storage.blockstore.make_store` falls back to the ``aio``
+thread pool (same ``BlockStore`` contract, so ``plan="external"``,
+``BatchQueue`` and the N_io tie-out are unaffected) unless ``strict``.
 
 O_DIRECT alignment: reads must be issued at the device's logical block
 granularity (512 B or 4 KiB). Block rows are ``2 * blkp * 4`` bytes at
@@ -27,8 +37,8 @@ arbitrary multiples from a page-aligned section start
 (``format.SpillHeader`` guarantees the section alignment), so each row
 read covers the *aligned extent* containing it: start rounded down, length
 rounded up, the row sliced out of the landing buffer. Landing buffers are
-anonymous ``mmap`` slots (page-aligned by construction), one per ring
-entry. The probe discovers the coarsest required alignment (512 then
+anonymous ``mmap`` slots (page-aligned by construction), one per read in
+flight. The probe discovers the coarsest required alignment (512 then
 4096) per file.
 """
 from __future__ import annotations
@@ -36,8 +46,10 @@ from __future__ import annotations
 import ctypes
 import mmap
 import os
+import queue
 import struct
 import tempfile
+import time
 from typing import Optional
 
 import numpy as np
@@ -61,8 +73,8 @@ _libc = ctypes.CDLL(None, use_errno=True)
 
 
 class UringUnavailable(OSError):
-    """io_uring (or a required capability) is not usable here; callers fall
-    back to the ``aio`` thread-pool backend."""
+    """Neither io_uring nor O_DIRECT is usable here (or io_uring setup
+    failed); callers fall back to the ``aio`` thread-pool backend."""
 
 
 class _SQOffsets(ctypes.Structure):
@@ -259,9 +271,10 @@ def capabilities(path=None) -> dict:
     * ``o_direct_align`` — smallest working O_DIRECT read alignment on
       ``path``'s filesystem (0 = unsupported; ``path`` defaults to the
       system temp dir, where scratch spills live);
-    * ``uring_store``    — the ``uring`` BlockStore can run at all
-      (io_uring present; O_DIRECT is optional — without it the store
-      batches submissions but reads through the page cache).
+    * ``uring_store``    — the ``uring`` BlockStore can run at all: io_uring
+      is present (O_DIRECT optional — without it the ring reads through the
+      page cache), or O_DIRECT works on ``path`` (misses go out as ``qd``
+      concurrent ``preadv`` calls).
     """
     ok, reason = probe_io_uring()
     align, d_reason = probe_o_direct(path if path is not None
@@ -269,7 +282,7 @@ def capabilities(path=None) -> dict:
     return dict(
         io_uring=ok, io_uring_reason=reason,
         o_direct_align=int(align), o_direct_reason=d_reason,
-        uring_store=ok,
+        uring_store=bool(ok or align),
         kernel=os.uname().release,
     )
 
@@ -282,23 +295,29 @@ from .blockstore import CachedBlockStore  # noqa: E402  (cycle-free: base only)
 
 
 class UringBlockStore(CachedBlockStore):
-    """Block reads through io_uring at queue depth ``qd``, O_DIRECT when the
-    filesystem allows it (the paper's Sec. 5.2 discipline, real).
+    """Block reads at queue depth ``qd``, O_DIRECT when the filesystem
+    allows it (the paper's Sec. 5.2 discipline, real).
 
     Same cache/ledger contract as the ``aio`` backend (one
     :class:`CachedBlockStore` base): batched cache resolution, misses to the
-    device, advisory prefetch joined in flight. The device path differs:
-    a miss batch becomes waves of up to ``qd`` reads, each wave ONE
-    ``io_uring_enter`` syscall submitting every read and blocking until the
-    wave completes — the kernel holds ``qd`` reads in flight against the
-    device, which is what actually buys T_async = max(compute, storage)
-    on real flash (Eq. 7). With O_DIRECT the reads bypass the page cache:
-    demand latency is device latency, the cache-defeating measurement mode
-    of docs/storage.md.
+    device, advisory prefetch joined in flight. The device path differs,
+    and ``submit`` says which one this store took:
 
-    The ring is driven from the store's single submitter thread (io_uring
-    is single-issuer here); demand batches and prefetch batches serialize
-    on it, each at full ring depth.
+    * ``"io_uring"`` — a miss batch becomes waves of up to ``qd`` reads,
+      each wave ONE ``io_uring_enter`` syscall submitting every read and
+      blocking until the wave completes — the kernel holds ``qd`` reads in
+      flight against the device, which is what actually buys T_async =
+      max(compute, storage) on real flash (Eq. 7). The ring is driven from
+      the store's single submitter thread (io_uring is single-issuer here);
+      demand and prefetch batches serialize on it, each at full ring depth.
+    * ``"pread"`` — no io_uring, O_DIRECT works: a miss batch splits over
+      ``qd`` pool workers, each ``preadv``-ing its rows' aligned extents
+      into its own landing slot, so ``qd`` reads are in flight.
+
+    With O_DIRECT the reads bypass the page cache: demand latency is device
+    latency, the cache-defeating measurement mode of docs/storage.md.
+    ``StoreStats.read_us`` sums it: each ``preadv`` is timed on its own, and
+    each read of a ring wave is charged the wave's wall time.
     """
 
     name = "uring"
@@ -307,44 +326,89 @@ class UringBlockStore(CachedBlockStore):
                  qd: int = 32, cache_rows: Optional[int] = None,
                  direct: bool = True):
         ok, reason = probe_io_uring()
-        if not ok:
-            raise UringUnavailable(0, reason)
-        self._ring = IoUring(qd)
-        self.qd = int(self._ring.entries)
-        self._base = int(offset)
-        self._stride = 2 * int(blkp) * 4
         # O_DIRECT per-file probe: refused (tmpfs) -> buffered fd, still
         # batched through the ring; callers read .o_direct for the mode
-        align = 0
-        if direct:
-            align, _ = probe_o_direct(path)
+        align, d_reason = (probe_o_direct(path) if direct
+                           else (0, "direct=False"))
+        if not ok and not align:
+            raise UringUnavailable(0, f"{reason}; no O_DIRECT: {d_reason}")
+        self.submit = "io_uring" if ok else "pread"
+        self._ring = IoUring(qd) if ok else None
+        self.qd = int(self._ring.entries) if ok else max(1, int(qd))
+        self._base = int(offset)
+        self._stride = 2 * int(blkp) * 4
         self.o_direct = bool(align)
         self.align = int(align) if align else 512
         flags = os.O_RDONLY | (_O_DIRECT if self.o_direct else 0)
         self._fd = os.open(os.fspath(path), flags)
-        # one page-aligned landing slot per ring entry, each big enough for
-        # a row's aligned covering extent
+        # one page-aligned landing slot per read in flight (ring entry or
+        # pool worker), each big enough for a row's aligned covering extent
         self._slot_len = self._align_up(self._stride) + self.align
         self._buf = mmap.mmap(-1, self._slot_len * self.qd)
-        self._buf_addr = ctypes.addressof(
-            ctypes.c_char.from_buffer(self._buf))
-        # single submitter thread: ring + landing buffers have one owner
+        if ok:
+            self._buf_addr = ctypes.addressof(
+                ctypes.c_char.from_buffer(self._buf))
+        else:
+            self._free_slots = queue.SimpleQueue()
+            for i in range(self.qd):
+                self._free_slots.put(i)
+        # ring: a single submitter thread owns the ring and the slots
         super().__init__(nb, blkp, qd=self.qd, cache_rows=cache_rows,
-                         workers=1)
+                         workers=1 if ok else self.qd)
 
     def _align_up(self, n: int) -> int:
         return -(-n // self.align) * self.align
 
+    def _row(self, slot_off: int, inner: int, g: int, got: int):
+        """Check a read's length and copy block row ``g`` out of its landing
+        slot (the row starts ``inner`` bytes into the aligned extent)."""
+        need = inner + self._stride
+        if got < need:
+            raise IOError(f"short {self.submit} read at block row {g}: "
+                          f"{got} < {need}")
+        return (np.frombuffer(self._buf, np.int32, count=self._stride // 4,
+                              offset=slot_off + inner)
+                .reshape(2, self.blkp).copy())
+
     # -- CachedBlockStore device hooks --------------------------------------
     def _device_chunks(self, rows: np.ndarray) -> list:
+        if self.submit == "pread":
+            return super()._device_chunks(rows)
         return [rows]          # one chunk: the ring IS the fan-out
 
     def _read_chunk(self, rows) -> dict:
+        rows = np.asarray(rows, dtype=np.int64).ravel()
+        if self.submit == "pread":
+            return self._pread_chunk(rows)
+        return self._ring_chunk(rows)
+
+    def _pread_chunk(self, rows: np.ndarray) -> dict:
+        from .format import aligned_extent
+
+        out, spent_ns = {}, 0
+        slot = self._free_slots.get()
+        lo = slot * self._slot_len
+        view = memoryview(self._buf)[lo:lo + self._slot_len]
+        try:
+            for g in rows:
+                astart, alen, off = aligned_extent(
+                    self._base + int(g) * self._stride, self._stride,
+                    self.align)
+                t0 = time.perf_counter_ns()
+                got = os.preadv(self._fd, [view[:alen]], astart)
+                spent_ns += time.perf_counter_ns() - t0
+                out[int(g)] = self._row(lo, off, int(g), got)
+        finally:
+            view.release()
+            self._free_slots.put(slot)
+            self._charge_read_ns(spent_ns)
+        return out
+
+    def _ring_chunk(self, rows: np.ndarray) -> dict:
         from ..telemetry import get_tracer
         from .format import aligned_extent
 
         out = {}
-        rows = np.asarray(rows, dtype=np.int64).ravel()
         stride, align = self._stride, self.align
         tracer = get_tracer()
         for wave_start in range(0, rows.size, self.qd):
@@ -362,20 +426,17 @@ class UringBlockStore(CachedBlockStore):
             # it lives on the caller thread, so the wave is its own root)
             with tracer.span("uring.wave", n=len(reads), qd=self.qd,
                              o_direct=self.o_direct):
+                t0 = time.perf_counter_ns()
                 res = self._ring.read_batch(self._fd, reads)
+                self._charge_read_ns((time.perf_counter_ns() - t0)
+                                     * len(reads))
             for i, g in enumerate(wave):
-                need = inner[i] + stride
                 if res[i] < 0:
                     raise IOError(
                         f"io_uring read of block row {int(g)} failed: "
                         f"{os.strerror(-res[i])}")
-                if res[i] < need:
-                    raise IOError(f"short io_uring read at block row "
-                                  f"{int(g)}: {res[i]} < {need}")
-                lo = i * self._slot_len + inner[i]
-                out[int(g)] = (np.frombuffer(self._buf, np.int32,
-                                             count=stride // 4, offset=lo)
-                               .reshape(2, self.blkp).copy())
+                out[int(g)] = self._row(i * self._slot_len, inner[i],
+                                        int(g), res[i])
         return out
 
     def close(self):

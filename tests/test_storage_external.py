@@ -22,13 +22,28 @@ _EXACT_FIELDS = ("ids", "found", "radii_searched", "nio_table", "nio_blocks",
 _BACKENDS = ("mem", "mmap", "aio", "uring")
 
 
-def _require_uring(path) -> None:
+def _require_uring(path, *, ring: bool = False) -> None:
     """Skip (with the probe's reason) where the real uring store can't run —
     the capability probe is the SAME gate make_store uses, so this skip
-    fires exactly when production would fall back to aio."""
+    fires exactly when production would fall back to aio. ``ring=True``
+    asks for io_uring itself, not the O_DIRECT pread path."""
     caps = st.capabilities(path)
-    if not caps["uring_store"]:
+    if not caps["io_uring" if ring else "uring_store"]:
         pytest.skip(f"io_uring unavailable: {caps['io_uring_reason']}")
+
+
+@pytest.fixture
+def no_io_uring(spilled, monkeypatch):
+    """io_uring switched off, as on a kernel without it; skips (with the
+    probe's reason) where the filesystem refuses O_DIRECT, since the uring
+    store then has no path left."""
+    import repro.storage.uring as uring_mod
+
+    align, reason = st.probe_o_direct(str(spilled))
+    if not align:
+        pytest.skip(f"O_DIRECT unavailable: {reason}")
+    monkeypatch.setattr(uring_mod, "probe_io_uring",
+                        lambda: (False, "forced off by test"))
 
 
 def _assert_matches(ref, out, *, probe_sizes=False):
@@ -335,12 +350,14 @@ def test_batch_queue_over_external_plan(storage_index, spilled, backend):
 
 def test_capabilities_report_shape(spilled):
     """The runtime probe reports every key the lanes and docs rely on, and
-    `uring_store` agrees with the io_uring probe (O_DIRECT is optional)."""
+    `uring_store` holds where io_uring or O_DIRECT works (either gives the
+    store a queue of reads that bypass the page cache)."""
     caps = st.capabilities(str(spilled))
     for key in ("io_uring", "io_uring_reason", "o_direct_align",
                 "o_direct_reason", "uring_store", "kernel"):
         assert key in caps, f"capabilities() lost key {key!r}"
-    assert caps["uring_store"] == caps["io_uring"]
+    assert caps["uring_store"] == (caps["io_uring"]
+                                   or caps["o_direct_align"] > 0)
     assert caps["o_direct_align"] in (0, 512, 4096)
     ok, reason = st.probe_io_uring()
     assert ok == caps["io_uring"] and isinstance(reason, str)
@@ -360,13 +377,16 @@ def test_aligned_extent_covers_and_aligns():
 
 
 def test_uring_fallback_to_aio_and_strict(spilled, monkeypatch):
-    """Where io_uring can't run, make_store('uring') degrades to the aio
-    backend (tagged with the reason) unless strict — the graceful-fallback
-    contract that keeps every uring-requesting caller working anywhere."""
+    """Where neither io_uring nor O_DIRECT can run, make_store('uring')
+    degrades to the aio backend (tagged with the reason) unless strict —
+    the graceful-fallback contract that keeps every uring-requesting caller
+    working anywhere."""
     import repro.storage.uring as uring_mod
 
     monkeypatch.setattr(uring_mod, "probe_io_uring",
                         lambda: (False, "forced off by test"))
+    monkeypatch.setattr(uring_mod, "probe_o_direct",
+                        lambda path: (0, "O_DIRECT refused by test"))
     with pytest.warns(RuntimeWarning, match="falling back"):
         ext = st.load_external(spilled, backend="uring", qd=4)
     with ext:
@@ -396,7 +416,7 @@ def test_store_backend_env_override(spilled, monkeypatch):
 def test_uring_buffered_mode_parity(storage_index, spilled, hard_queries):
     """direct=False keeps the ring but reads through the page cache — the
     submission discipline alone must not change any result."""
-    _require_uring(str(spilled))
+    _require_uring(str(spilled), ring=True)
     ref = SearchEngine(storage_index[0]).query(hard_queries, plan="fused",
                                                k=2)
     with st.load_external(spilled, backend="uring", qd=8,
@@ -423,21 +443,95 @@ def test_uring_wave_larger_than_ring(storage_index, spilled):
         assert s.reads == s.device_reads + s.cache_hits == rows.size
 
 
+def test_uring_without_io_uring_reads_o_direct_from_a_pread_pool(
+        storage_index, spilled, hard_queries, no_io_uring):
+    """No io_uring, O_DIRECT works: the strict uring store keeps its
+    contract on the pread path — O_DIRECT reads, qd workers — with rows
+    bit-identical to mem, the same ledger, and plan="external" over it
+    bit-exact with plan="fused"."""
+    hdr = st.read_header(spilled)
+    nb = hdr.nb
+    rows = np.random.default_rng(5).permutation(np.arange(1, nb))[:97]
+    with st.make_store("uring", spilled, hdr, qd=8, strict=True) as store, \
+            st.make_store("mem", spilled, hdr) as mem:
+        assert store.name == "uring" and store.submit == "pread"
+        assert store.o_direct and store.qd == 8
+        assert getattr(store, "fallback_from", None) is None
+        got, want = store.read_rows(rows), mem.read_rows(rows)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)
+        ledger = {f: getattr(store.stats, f) for f in
+                  ("reads", "device_reads", "cache_hits", "prefetch_reads",
+                   "read_batches")}
+        assert ledger == {f: getattr(mem.stats, f) for f in ledger}
+    ref = SearchEngine(storage_index[0]).query(hard_queries, plan="fused",
+                                               k=3, collect_probe_sizes=True)
+    with st.load_external(spilled, backend="uring", qd=8,
+                          strict=True) as ext:
+        assert ext.store.submit == "pread"
+        out = SearchEngine(ext).query(hard_queries, k=3,
+                                      collect_probe_sizes=True)
+        _assert_matches(ref, out, probe_sizes=True)
+        ps = ext.last_plan_stats
+        assert ps.measured_nio_blocks == int(np.asarray(out.nio_blocks).sum())
+
+
+@pytest.mark.parametrize("submit", ("io_uring", "pread"))
+def test_read_us_counts_device_reads_not_cache_hits(spilled, request,
+                                                    submit):
+    """read_us grows with every read issued to the file, shows on the
+    store.read span and in the registry, and stays put over a batch the
+    cache serves whole."""
+    from repro import telemetry
+
+    if submit == "pread":
+        request.getfixturevalue("no_io_uring")
+    else:
+        _require_uring(str(spilled), ring=True)
+    hdr = st.read_header(spilled)
+    rows = np.arange(1, min(hdr.nb, 65), dtype=np.int64)
+    tr = telemetry.enable(sampling=1.0)
+    tr.clear()
+    try:
+        with st.make_store("uring", spilled, hdr, qd=4, strict=True) as s:
+            assert s.submit == submit
+            s.read_rows(rows)
+            cold = s.stats.snapshot()
+            assert cold.device_reads == rows.size and cold.read_us > 0
+            s.read_rows(rows)
+            warm = s.stats.since(cold)
+            assert warm.cache_hits == rows.size and warm.device_reads == 0
+            assert warm.read_us == 0
+            snap = telemetry.snapshot()["e2lsh_store_read_us_total"]
+            assert snap["type"] == "counter"
+            assert sum(x["value"] for x in snap["samples"]
+                       if x["labels"]["backend"] == "uring") >= cold.read_us
+        spans = [sp for sp in tr.spans() if sp.name == "store.read"]
+        assert [sp.attrs["read_us"] for sp in spans] == [cold.read_us, 0]
+    finally:
+        telemetry.disable()
+        tr.clear()
+
+
 # --------------------------------------------------------------------------
 # StoreStats invariants under concurrency
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ("aio", "uring"))
+@pytest.mark.parametrize("backend", ("aio", "uring", "pread"))
 def test_store_stats_ledger_under_concurrency(storage_index, spilled,
-                                              backend):
+                                              backend, request):
     """prefetch() racing read_rows() across threads: the ledger must keep
     `reads == device_reads + cache_hits` exactly, `reads` must equal the
     demand rows requested (prefetch_reads NEVER leak into logical N_io),
-    and every returned row must be correct."""
+    and every returned row must be correct. ``pread`` is the uring store
+    without io_uring: its qd workers share the landing slots."""
     import threading
 
     if backend == "uring":
         _require_uring(str(spilled))
+    if backend == "pread":
+        request.getfixturevalue("no_io_uring")
+        backend = "uring"
     idx, _ = storage_index
     blocks = np.stack([np.asarray(idx.index.arrays.ids_blocks),
                        np.asarray(idx.index.arrays.fps_blocks)], axis=1)
@@ -490,3 +584,4 @@ def test_store_stats_ledger_under_concurrency(storage_index, spilled,
             "logical N_io drifted from the demand rows requested "
             f"({s.reads} != {demand.size}) — prefetch leaked into reads")
         assert s.prefetch_reads <= spec_rows.size
+        assert s.read_us > 0
