@@ -59,7 +59,8 @@ class TickStats:
     occupancy: float     # rows / shape
     dispatch_ms: float   # launch_ms + wait_ms: the single fused dispatch
     shed: int = 0        # tickets shed (DeadlineExceeded) at this tick's pack
-    # host phases, ms on perf_counter; contiguous, so they sum to <= wall_ms
+    # host phases, ms on perf_counter; each the tick's own, so they sum to
+    # <= wall_ms (back to back unless the tick was left in flight)
     pack_ms: float = 0.0     # lock, pack, shed, pad to the rung
     launch_ms: float = 0.0   # inputs to the device + plan call (enqueue)
     wait_ms: float = 0.0     # block_until_ready: the host waits on the device
@@ -67,10 +68,30 @@ class TickStats:
     deliver_ms: float = 0.0  # per-segment slices, ticket delivery, QoS records
     wall_ms: float = 0.0     # the whole tick, from its start to its commit
     queued_ms: float = 0.0   # mean over segments of pack time - submit time
+    overlapped: bool = False  # launched while an earlier tick was in flight
 
 
 # the host phases of one tick, in order; each is a TickStats ``<phase>_ms``
 TICK_PHASES = ("pack", "launch", "wait", "fetch", "deliver")
+
+
+@dataclasses.dataclass
+class _Launched:
+    """One tick between its plan call and its delivery."""
+
+    batch: list          # its _Pending segments, in row order
+    rows: int
+    shape: int
+    shed: int
+    now: float           # time.monotonic() at pack
+    t_start: float       # perf_counter() at the tick's start
+    pack_ms: float
+    launch_ms: float
+    res: Any             # the plan's QueryResult, possibly still computing
+    ready: bool          # res.ids was complete when the launch returned
+    overlapped: bool     # launched while an earlier tick was in flight
+    root: Any            # its serve.tick span
+    dsp: Any             # its tick.dispatch span
 
 
 @dataclasses.dataclass
@@ -107,6 +128,8 @@ class QueryTicket:
 
     def _deliver(self, seg_idx: int, part: QueryResult) -> None:
         with self._lock:
+            if self._error is not None:   # a sibling segment's tick failed
+                return
             self._parts[seg_idx] = part
             self._remaining -= 1
             if self._remaining > 0:
@@ -117,8 +140,11 @@ class QueryTicket:
 
     def _fail(self, exc: BaseException) -> None:
         """A tick's dispatch died: resolve the ticket with the error so
-        waiters raise instead of hanging forever."""
+        waiters raise instead of hanging forever. A ticket already served
+        or failed keeps its outcome."""
         with self._lock:
+            if self._result is not None or self._error is not None:
+                return
             self._error = exc
             self._parts = []
         self._event.set()
@@ -191,6 +217,15 @@ class BatchQueue:
     the background loop (``start()``/``stop()``), which fires a tick every
     ``tick_us`` microseconds while requests are pending and services
     back-to-back full ticks immediately under queue pressure.
+
+    **One tick in flight.** The background loop launches tick N+1 before it
+    waits on, fetches and delivers tick N, so the device runs N+1 while the
+    host finishes N — but only where N's result was not yet complete when
+    its launch returned (``res.ids.is_ready()``). A plan that does its work
+    inside the call (the external plan) is completed before the next pack,
+    as the synchronous entry points always are; they first complete a tick
+    the loop left in flight. ``TickStats.overlapped`` marks each tick
+    launched while an earlier one was in flight.
     """
 
     @staticmethod
@@ -232,6 +267,7 @@ class BatchQueue:
         self._lock = threading.Lock()        # guards _pending / _seq
         self._serve_lock = threading.Lock()  # serializes whole ticks
         self._thread: Optional[threading.Thread] = None
+        self._inflight: Optional[_Launched] = None  # the loop's launched tick
         self._stop = threading.Event()
         self._seq = 0                    # submission counter (FIFO tiebreak)
         self._qos_pending = 0            # pending segments with QoS attrs
@@ -357,25 +393,59 @@ class BatchQueue:
         order (strict priority, EDF within class, FIFO tiebreak) up to
         max_batch rows, pad + mask to the smallest ladder rung, dispatch
         ONCE, scatter back. Returns None (no dispatch) when nothing packed.
+        A tick the background loop left in flight is completed first, so
+        the call still serves its own batch before it returns.
         Thread-safe: whole ticks are serialized (concurrent callers — e.g.
         several synchronous query() drains — each serve complete ticks,
         never interleave one)."""
         with self._serve_lock:
             tr = get_tracer()
-            root = tr.begin("serve.tick", plan=self.plan)
-            try:
-                return self._tick_locked(tr, root)
-            finally:
-                root.end()
+            self._settle(tr)
+            fl = self._launch(tr, overlapped=False)
+            return None if fl is None else self._complete(tr, fl)
 
-    def _tick_locked(self, tr, root) -> Optional[TickStats]:
-        """The tick body, under ``_serve_lock`` with its root span open.
-        Each phase is one child span (recorded only while tracing is on)
-        and one ``perf_counter`` read at its end (always)."""
-        t_start = time.perf_counter()
-        now = time.monotonic()
+    def _settle(self, tr) -> None:
+        """Complete the tick left in flight, if any (under ``_serve_lock``)."""
+        fl, self._inflight = self._inflight, None
+        if fl is not None:
+            self._complete(tr, fl)
+
+    def _step(self) -> Optional[int]:
+        """One pass of the background loop: launch the next tick, then
+        complete the one in flight, so the device runs the next tick while
+        the host waits on, fetches and delivers this one. A tick whose
+        result is already complete when its launch returns (a plan that did
+        its work inside the call) is completed in the same pass, before
+        anything else is packed; one that is not stays in flight until the
+        next pass. Returns the rows launched (None: nothing packed). A
+        failed tick fails only its own tickets; the first error is raised
+        once the other tick has been dealt with."""
+        with self._serve_lock:
+            tr = get_tracer()
+            prev, self._inflight = self._inflight, None
+            errors, fl = [], None
+            try:
+                fl = self._launch(tr, overlapped=prev is not None)
+            except Exception as e:
+                errors.append(e)
+            done = [prev] if prev is not None else []
+            if fl is not None and fl.ready:
+                done.append(fl)
+            elif fl is not None:
+                self._inflight = fl
+            for t in done:
+                try:
+                    self._complete(tr, t)
+                except Exception as e:
+                    errors.append(e)
+            if errors:
+                raise errors[0]
+            return None if fl is None else fl.rows
+
+    def _pack(self, now: float) -> tuple:
+        """Pop the next tick's segments in QoS order and shed the expired
+        ones: ``(batch, rows, shed)``."""
         urgent_s = 2.0 * self.tick_us * 1e-6   # slack beating shape reuse
-        psp = tr.begin("tick.pack")
         with self._lock:
             shed_tickets: dict = {}
             target = self._target_rows()
@@ -432,7 +502,6 @@ class BatchQueue:
                 self._qos_pending = sum(
                     1 for e in self._pending
                     if e.priority != 0 or e.deadline is not None)
-        n_shed = len(shed_tickets)
         for t in shed_tickets.values():
             with self._stats_lock:
                 self.shed_count += 1
@@ -441,10 +510,24 @@ class BatchQueue:
                 f"request shed: {budget_ms:.1f}ms deadline expired "
                 f"{(now - t.deadline) * 1e3:.1f}ms before its tick"))
             self._record_qos(t, now=now, shed=True)
+        return batch, rows, len(shed_tickets)
+
+    def _launch(self, tr, *, overlapped: bool) -> Optional[_Launched]:
+        """Pack a tick and launch its plan call; None when nothing packed.
+        The tick's ``serve.tick`` and ``tick.dispatch`` spans stay open
+        until it completes, possibly after the next tick's launch, so they
+        are detached and every phase span names its parent. A failed
+        launch fails the tick's tickets and raises."""
+        t_start = time.perf_counter()
+        now = time.monotonic()
+        root = tr.begin("serve.tick", detached=True, plan=self.plan)
+        psp = tr.begin("tick.pack", parent=root)
+        batch, rows, n_shed = self._pack(now)
         if not batch:
             if n_shed:
                 psp.set(segments=0, rows=0, shed=n_shed)
                 psp.end()
+                root.end()
             else:
                 psp.cancel()      # idle poll: keep the span ring quiet
                 root.cancel()     # nothing happened; drop the empty tick
@@ -457,43 +540,69 @@ class BatchQueue:
         psp.set(segments=len(batch), rows=rows, shed=n_shed)
         psp.end()
         t_pack = time.perf_counter()
+        root.set(shape=shape, rows=rows, segments=len(batch))
+        dsp = tr.begin("tick.dispatch", detached=True, parent=root,
+                       shape=shape, rows=rows)
         try:
-            with tr.span("tick.dispatch", shape=shape, rows=rows):
-                with tr.span("tick.launch"):
-                    res = self._fn(jnp.asarray(qs), jnp.asarray(valid))
-                t_launch = time.perf_counter()
-                with tr.span("tick.wait"):
-                    jax.block_until_ready(res.ids)
+            with tr.span("tick.launch", parent=dsp):
+                res = self._fn(jnp.asarray(qs), jnp.asarray(valid))
         except Exception as e:
             # the popped segments can never be re-served at this point:
             # fail their tickets (waiters raise instead of hanging) and
             # surface the error to whoever drove the tick
             for p in batch:
                 p.ticket._fail(e)
+            dsp.end()
+            root.end()
             raise
-        t_wait = time.perf_counter()
-        root.set(shape=shape, rows=rows, segments=len(batch))
-        # ONE device->host transfer for the whole tick; the per-segment
-        # scatter is then numpy views (per-segment device slicing costs
-        # more than the dispatch itself at high request counts)
-        with tr.span("tick.scatter", segments=len(batch)):
-            with tr.span("tick.fetch"):
-                host = jax.device_get(res)
-            t_fetch = time.perf_counter()
-            with tr.span("tick.deliver"):
-                done_t = time.monotonic()
-                lo = 0
-                for p in batch:
-                    hi = lo + p.seg.shape[0]
-                    p.ticket._deliver(p.seg_idx, host.slice_rows(lo, hi))
-                    lo = hi
-                    if p.ticket.done():
-                        self._record_qos(p.ticket, now=done_t, shed=False)
+        t_launch = time.perf_counter()
+        return _Launched(
+            batch=batch, rows=rows, shape=shape, shed=n_shed, now=now,
+            t_start=t_start, pack_ms=(t_pack - t_start) * 1e3,
+            launch_ms=(t_launch - t_pack) * 1e3, res=res,
+            ready=res.ids.is_ready(), overlapped=overlapped, root=root,
+            dsp=dsp)
+
+    def _complete(self, tr, fl: _Launched) -> TickStats:
+        """Wait for a launched tick, fetch its result, deliver each segment
+        and commit its stats. A failed wait, fetch or delivery fails the
+        tick's unserved tickets and raises."""
+        batch = fl.batch
+        t_wait0 = time.perf_counter()
+        try:
+            try:
+                with tr.span("tick.wait", parent=fl.dsp):
+                    jax.block_until_ready(fl.res.ids)
+            finally:
+                fl.dsp.end()
+            t_wait = time.perf_counter()
+            # ONE device->host transfer for the whole tick; the per-segment
+            # scatter is then numpy views (per-segment device slicing costs
+            # more than the dispatch itself at high request counts)
+            with tr.span("tick.scatter", parent=fl.root,
+                         segments=len(batch)):
+                with tr.span("tick.fetch"):
+                    host = jax.device_get(fl.res)
+                t_fetch = time.perf_counter()
+                with tr.span("tick.deliver"):
+                    done_t = time.monotonic()
+                    lo = 0
+                    for p in batch:
+                        hi = lo + p.seg.shape[0]
+                        p.ticket._deliver(p.seg_idx, host.slice_rows(lo, hi))
+                        lo = hi
+                        if p.ticket.done():
+                            self._record_qos(p.ticket, now=done_t,
+                                             shed=False)
+        except Exception as e:
+            for p in batch:
+                p.ticket._fail(e)
+            fl.root.end()
+            raise
         t_end = time.perf_counter()
-        queued_ms = [(now - p.ticket.submit_t) * 1e3 for p in batch]
-        phase_ms = dict(pack=(t_pack - t_start) * 1e3,
-                        launch=(t_launch - t_pack) * 1e3,
-                        wait=(t_wait - t_launch) * 1e3,
+        queued_ms = [(fl.now - p.ticket.submit_t) * 1e3 for p in batch]
+        phase_ms = dict(pack=fl.pack_ms, launch=fl.launch_ms,
+                        wait=(t_wait - t_wait0) * 1e3,
                         fetch=(t_fetch - t_wait) * 1e3,
                         deliver=(t_end - t_fetch) * 1e3)
         for phase, ms in phase_ms.items():
@@ -505,16 +614,18 @@ class BatchQueue:
         with self._stats_lock:
             self.dispatch_count += 1
             stats = TickStats(
-                tick=len(self.tick_log), shape=shape, rows=rows,
-                segments=len(batch), pad_rows=shape - rows,
-                occupancy=rows / shape,
+                tick=len(self.tick_log), shape=fl.shape, rows=fl.rows,
+                segments=len(batch), pad_rows=fl.shape - fl.rows,
+                occupancy=fl.rows / fl.shape,
                 dispatch_ms=phase_ms["launch"] + phase_ms["wait"],
-                shed=n_shed, **{f"{k}_ms": v for k, v in phase_ms.items()},
-                wall_ms=(time.perf_counter() - t_start) * 1e3,
+                shed=fl.shed, **{f"{k}_ms": v for k, v in phase_ms.items()},
+                wall_ms=(time.perf_counter() - fl.t_start) * 1e3,
                 queued_ms=sum(queued_ms) / len(queued_ms),
+                overlapped=fl.overlapped,
             )
             self.tick_log.append(stats)
-        root.set(tick=stats.tick)
+        fl.root.set(tick=stats.tick)
+        fl.root.end()
         return stats
 
     def drain(self) -> int:
@@ -547,7 +658,8 @@ class BatchQueue:
     # -- background loop ----------------------------------------------------
     def start(self) -> "BatchQueue":
         """Run the tick loop on a daemon thread (tick every tick_us while
-        idle-ish; full ticks are followed immediately under pressure)."""
+        idle-ish; full ticks are followed immediately under pressure, with
+        one tick in flight while the last one completes: ``_step``)."""
         if self._thread is not None:
             return self
         self._stop.clear()
@@ -555,14 +667,14 @@ class BatchQueue:
         def loop():
             while not self._stop.is_set():
                 try:
-                    st = self.tick()
+                    rows = self._step()
                 except Exception:
-                    # the affected tickets were failed inside tick(); keep
+                    # the affected tickets were failed inside the step; keep
                     # the loop alive for the next batch instead of dying
                     # silently with requests still flowing in
-                    st = None
-                if st is None or st.rows < self.max_batch:
-                    if (st is None and self.warm_cache_rows > 0
+                    rows = None
+                if rows is None or rows < self.max_batch:
+                    if (rows is None and self.warm_cache_rows > 0
                             and self.dispatch_count != self._warmed_at):
                         # idle: re-warm the store cache from the probe trace
                         # (once per dispatch generation — the histogram only
@@ -570,6 +682,11 @@ class BatchQueue:
                         self._warmed_at = self.dispatch_count
                         self.warm_cache()
                     self._stop.wait(self.tick_us * 1e-6)
+            with self._serve_lock:
+                try:
+                    self._settle(get_tracer())
+                except Exception:
+                    pass          # its tickets were failed in _complete
 
         self._thread = threading.Thread(
             target=loop, name="batch-queue-tick", daemon=True)
@@ -597,8 +714,9 @@ class BatchQueue:
         the ladder-rung histogram, the mean of each host phase
         (``pack_ms`` ... ``deliver_ms``) and of the whole tick
         (``tick_wall_ms``), queue wait from submit to pack p50/p99 (over
-        the ticks' means), and the QoS block (shed counts + deadline hit
-        rates, overall and per priority class).
+        the ticks' means), the share of ticks launched while an earlier
+        tick was in flight (``overlap_share``), and the QoS block (shed
+        counts + deadline hit rates, overall and per priority class).
 
         ``window=N`` restricts the tick aggregates to the last N ticks (the
         sliding view the adaptive packer sees); the default is cumulative.
@@ -646,6 +764,7 @@ class BatchQueue:
                 tick_wall_ms=float(np.mean([t.wall_ms for t in log])),
                 p50_queued_ms=float(np.percentile(qms, 50)),
                 p99_queued_ms=float(np.percentile(qms, 99)),
+                overlap_share=float(np.mean([t.overlapped for t in log])),
             )
             for p in TICK_PHASES:
                 out[f"{p}_ms"] = float(np.mean(
@@ -739,7 +858,7 @@ def _collect_queue_metrics() -> dict:
         by_plan.setdefault(c["plan"], []).append(c)
 
     counters = dict(ticks=[], dispatches=[], rows=[], pad_rows=[],
-                    segments=[], shed=[])
+                    segments=[], shed=[], overlapped_ticks=[])
     gauges = dict(queue_depth=[], occupancy_mean=[], deadline_hit_rate=[])
     rungs, cls_tickets, cls_shed, cls_hit = [], [], [], []
     for plan, group in sorted(by_plan.items()):
@@ -757,6 +876,8 @@ def _collect_queue_metrics() -> dict:
             labels=lab, value=sum(t.segments for t in log)))
         counters["shed"].append(dict(
             labels=lab, value=sum(c["shed"] for c in group)))
+        counters["overlapped_ticks"].append(dict(
+            labels=lab, value=sum(1 for t in log if t.overlapped)))
         gauges["queue_depth"].append(dict(
             labels=lab, value=sum(c["depth"] for c in group)))
         if log:
@@ -792,6 +913,7 @@ def _collect_queue_metrics() -> dict:
         pad_rows="masked padding rows dispatched",
         segments="request segments packed",
         shed="tickets shed with DeadlineExceeded",
+        overlapped_ticks="ticks launched while an earlier tick was in flight",
     )
     out = {f"e2lsh_serve_{k}_total": dict(type="counter", help=helps[k],
                                           samples=v)

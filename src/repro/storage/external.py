@@ -561,6 +561,8 @@ def external_plan(ext: ExternalIndex, queries, cfg: QueryConfig,
         with _TOTALS_LOCK:       # accumulate, never overwrite (queue-safe)
             ext.plan_totals.add(ps)
         root.set(queries=realQ, rungs=len(rungs), nio_blocks=ps.io.reads)
-        return res
+        # complete on return: a caller (BatchQueue) reads readiness to tell
+        # a plan that did its work inside the call from one still computing
+        return jax.block_until_ready(res)
     finally:
         root.end()

@@ -168,19 +168,27 @@ class Tracer:
         return self
 
     # -- span creation ------------------------------------------------------
-    def begin(self, name: str, *, detached: bool = False, **attrs):
+    def begin(self, name: str, *, detached: bool = False,
+              parent=None, **attrs):
         """Start a span. Attached spans (default) push the thread-local
         stack, so spans begun under them become children; ``detached=True``
         skips the stack — for regions ended from another thread or out of
-        lexical order (async waves). Returns the no-op span when disabled."""
+        lexical order (async waves). ``parent=`` names the parent span
+        outright instead of the stack's top — for trees whose regions
+        interleave with another tree's (a tick still in flight while the
+        next one launches); a no-op parent gives a no-op child. Returns
+        the no-op span when disabled."""
         if not self._enabled:
+            return NOOP_SPAN
+        if parent is not None and not isinstance(parent, Span):
             return NOOP_SPAN
         tls = self._tls
         stack = getattr(tls, "stack", None)
         if stack is None:
             stack = tls.stack = []
-        if stack:
+        if parent is None and stack:
             parent = stack[-1]
+        if parent is not None:
             sampled = parent.sampled
             pid = parent.sid
         else:

@@ -7,6 +7,7 @@ core.query mask contract, and every Q>=1 dispatch rides the same row-stable
 gemm path (core.query._pad_min_q). Steady state is ONE fused dispatch per
 tick: the ladder is warmed at construction, so ticks never retrace.
 """
+import dataclasses
 import threading
 
 import jax
@@ -22,6 +23,7 @@ except ImportError:
 
 from repro.core import E2LSHoS, SearchEngine
 from repro.serving import BatchQueue, TickStats
+from repro.storage import load_external
 from repro.serving.engine import TICK_PHASES
 
 _EXACT_FIELDS = ("ids", "dists", "found", "radii_searched", "nio_table",
@@ -49,7 +51,8 @@ def queue_env():
         base = db[rng_q.choice(n, b, replace=False)]
         return (base + 0.05 * rng_q.normal(size=base.shape)).astype(np.float32)
 
-    return dict(engine=engine, direct=direct, make_request=make_request, d=d)
+    return dict(engine=engine, direct=direct, make_request=make_request, d=d,
+                idx=idx)
 
 
 def _fresh_queue(env, **kw):
@@ -323,3 +326,227 @@ def test_queue_over_oracle_and_host_plans(queue_env):
         want = queue_env["engine"].query(req, plan=plan, k=2)
         np.testing.assert_array_equal(np.asarray(got.ids),
                                       np.asarray(want.ids), err_msg=plan)
+
+
+# --------------------------------------------------------------------------
+# One tick in flight: the background loop launches tick N+1 before it waits
+# on, fetches and delivers tick N, wherever N's result is not yet complete
+# when its launch returns.
+
+class _GatedIds:
+    """The ``ids`` of a stub plan's result: not ready, and held at the
+    wait, until the test opens ``gate``; ``waiting`` is set when the queue
+    starts to wait; ``fail_fetch`` makes the transfer to the host raise."""
+
+    def __init__(self, ids, gate, waiting=None, fail_fetch=False):
+        self._ids, self._gate = ids, gate
+        self._waiting, self._fail_fetch = waiting, fail_fetch
+
+    def is_ready(self):
+        return self._gate.is_set()
+
+    def block_until_ready(self):
+        if self._waiting is not None:
+            self._waiting.set()
+        assert self._gate.wait(60.0), "the test never opened the gate"
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        if self._fail_fetch:
+            raise RuntimeError("injected fetch failure")
+        return np.asarray(self._ids, dtype=dtype)
+
+
+def _gate_plan(queue, gates, **kw):
+    """Make launch i of ``queue`` return the real result with its ids
+    behind ``gates[i]`` (launches past the list reuse the last gate)."""
+    real_fn, calls = queue._fn, []
+
+    def gated(qs, valid):
+        res = real_fn(qs, valid)
+        gate = gates[min(len(calls), len(gates) - 1)]
+        calls.append(gate)
+        return dataclasses.replace(res, ids=_GatedIds(res.ids, gate, **kw))
+
+    queue._fn = gated
+    return calls
+
+
+def _assert_exact(got, want, what):
+    for name in _EXACT_FIELDS:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, name)), np.asarray(getattr(want, name)),
+            err_msg=f"{what}: {name}")
+
+
+def test_loop_launches_next_tick_before_fetching_the_last(queue_env):
+    """With results that are not ready at launch, the loop launches tick 1
+    while tick 0 is still waited on (tick 0 unserved at tick 1's launch),
+    flags the overlapped ticks, counts them in overlap_share, and every
+    request is still bit-exact with a direct dispatch."""
+    queue = _fresh_queue(queue_env)
+    gate, waiting = threading.Event(), threading.Event()
+    real_fn = queue._fn
+    requests = [queue_env["make_request"](MAX_BATCH) for _ in range(3)]
+    tickets = [queue.submit(r) for r in requests]     # before the loop runs
+    seen = []                  # at each launch: which tickets were served
+
+    def recording(qs, valid):
+        seen.append([t.done() for t in tickets])
+        return real_fn(qs, valid)
+
+    queue._fn = recording
+    _gate_plan(queue, [gate], waiting=waiting)
+    queue.start()
+    try:
+        # the loop waits on tick 0 only after tick 1's launch has returned
+        assert waiting.wait(60.0)
+        assert len(seen) == 2 and seen[1] == [False, False, False]
+        gate.set()
+        results = [t.result(timeout=60.0) for t in tickets]
+    finally:
+        gate.set()
+        queue.stop()
+    for req, got in zip(requests, results):
+        _assert_exact(got, queue_env["direct"](req), "overlapped tick")
+    # tick 2 launched behind tick 1, after the gate opened: ready at launch
+    assert [t.overlapped for t in queue.tick_log] == [False, True, True]
+    assert queue.stats_summary()["overlap_share"] == pytest.approx(2 / 3)
+    assert queue.dispatch_count == len(queue.tick_log) == 3
+
+
+@pytest.mark.parametrize("plan", ["stub", "external"])
+def test_ready_results_never_overlap(queue_env, tmp_path, plan):
+    """A plan whose result is complete when its call returns (a stub that
+    blocks before it returns; the external plan over the mem store) never
+    overlaps: each tick is delivered before the next is packed, in FIFO
+    order, bit-exact with a direct call."""
+    sizes = (MAX_BATCH, 5, MAX_BATCH, 9)     # one request per tick
+    requests = [queue_env["make_request"](b) for b in sizes]
+    with load_external(_spill(queue_env, tmp_path), backend="mem") as ext:
+        if plan == "external":
+            engine = SearchEngine(ext)
+            queue = BatchQueue(engine, plan="external", k=2, ladder=LADDER,
+                               max_batch=MAX_BATCH)
+            direct = engine.make_plan_fn(plan="external", k=2)[1]
+            inner = queue._fn
+        else:
+            queue, direct = _fresh_queue(queue_env), queue_env["direct"]
+            real_fn = queue._fn
+
+            def inner(qs, valid):
+                return jax.block_until_ready(real_fn(qs, valid))
+        tickets, seen = [], []
+
+        def recording(qs, valid):
+            seen.append([t.done() for t in tickets])
+            return inner(qs, valid)
+
+        queue._fn = recording
+        tickets.extend(queue.submit(r) for r in requests)
+        with queue:
+            results = [t.result(timeout=60.0) for t in tickets]
+        assert seen == [[True] * i + [False] * (len(sizes) - i)
+                        for i in range(len(sizes))]
+        for req, got in zip(requests, results):
+            _assert_exact(got, direct(req), f"{plan} queued")
+    assert [t.rows for t in queue.tick_log] == list(sizes)
+    assert not any(t.overlapped for t in queue.tick_log)
+    assert queue.stats_summary()["overlap_share"] == 0.0
+
+
+def _spill(env, tmp_path):
+    path = tmp_path / "index.e2l"
+    env["idx"].index.spill(path)
+    return path
+
+
+def test_launch_failure_fails_only_its_tick(queue_env):
+    """Tick 1's launch dies while tick 0 is in flight: tick 1's tickets
+    fail, tick 0 is still delivered, and the error surfaces."""
+    queue = _fresh_queue(queue_env)
+    gate = threading.Event()
+    _gate_plan(queue, [gate])
+    gated_fn, calls = queue._fn, []
+
+    def flaky(qs, valid):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("injected launch failure")
+        return gated_fn(qs, valid)
+
+    queue._fn = flaky
+    requests = [queue_env["make_request"](MAX_BATCH) for _ in range(2)]
+    first, second = (queue.submit(r) for r in requests)
+    assert queue._step() == MAX_BATCH         # tick 0 left in flight
+    assert not first.done() and queue.dispatch_count == 0
+    gate.set()
+    with pytest.raises(RuntimeError, match="injected launch"):
+        queue._step()
+    _assert_exact(first.result(timeout=0), queue_env["direct"](requests[0]),
+                  "tick in flight behind a failed launch")
+    with pytest.raises(RuntimeError, match="injected launch"):
+        second.result(timeout=0)
+    assert queue.dispatch_count == len(queue.tick_log) == 1
+
+
+def test_fetch_failure_fails_only_its_tick(queue_env):
+    """Tick 0's fetch dies after tick 1 was launched behind it: tick 0's
+    tickets fail (the request whose tail rides in tick 1 too), tick 1
+    stays in flight and drain() delivers its other request."""
+    queue = _fresh_queue(queue_env)
+    gates = [threading.Event(), threading.Event()]
+    real_fn = queue._fn
+    calls = _gate_plan(queue, gates)
+    gated_fn = queue._fn
+
+    def failing_fetch(qs, valid):
+        res = gated_fn(qs, valid)
+        if len(calls) == 1:
+            res = dataclasses.replace(res, ids=_GatedIds(
+                real_fn(qs, valid).ids, gates[0], fail_fetch=True))
+        return res
+
+    queue._fn = failing_fetch
+    requests = [queue_env["make_request"](b) for b in (MAX_BATCH + 3, 5)]
+    first, second = (queue.submit(r) for r in requests)
+    queue._step()                             # tick 0 in flight
+    gates[0].set()
+    with pytest.raises(RuntimeError, match="injected fetch"):
+        queue._step()                         # tick 1 launched, tick 0 dies
+    with pytest.raises(RuntimeError, match="injected fetch"):
+        first.result(timeout=0)
+    assert not second.done() and queue.dispatch_count == 0
+    gates[1].set()
+    assert queue.drain() == 0                 # completes tick 1, packs none
+    _assert_exact(second.result(timeout=0), queue_env["direct"](requests[1]),
+                  "tick in flight behind a failed fetch")
+    assert [(t.rows, t.overlapped) for t in queue.tick_log] == [(8, True)]
+    assert queue.dispatch_count == len(queue.tick_log) == 1
+
+
+@pytest.mark.parametrize("entry", ["tick", "drain", "stop"])
+def test_sync_entry_points_complete_a_tick_in_flight(queue_env, entry):
+    """tick(), drain() and stop(drain=True) first complete a tick the loop
+    left in flight, then serve what is pending as before."""
+    queue = _fresh_queue(queue_env)
+    gate = threading.Event()
+    _gate_plan(queue, [gate])
+    requests = [queue_env["make_request"](b) for b in (MAX_BATCH, 3)]
+    tickets = [queue.submit(r) for r in requests]
+    queue._step()                             # tick 0 left in flight
+    assert not tickets[0].done() and queue.dispatch_count == 0
+    gate.set()
+    if entry == "tick":
+        stats = queue.tick()                    # serves its own batch too
+        assert (stats.rows, stats.overlapped) == (3, False)
+    elif entry == "drain":
+        assert queue.drain() == 1
+    else:
+        queue.start()
+        queue.stop(drain=True)
+    for req, t in zip(requests, tickets):
+        _assert_exact(t.result(timeout=0), queue_env["direct"](req), entry)
+    assert [t.rows for t in queue.tick_log] == [MAX_BATCH, 3]
+    assert queue.dispatch_count == len(queue.tick_log) == 2
+    assert queue._inflight is None

@@ -9,11 +9,14 @@ equals the plan's ``measured_nio_blocks``, equals the Eq. 6/7 io_count
 replay of the recorded probe trace, on every backend. Telemetry reports the
 pinned ledger semantics; it never gets its own parallel truth.
 """
+import dataclasses
+import gc
 import json
 import threading
 import time
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -239,6 +242,29 @@ def test_detached_span_does_not_parent():
         pass
     wave.end()
     assert other.parent is None
+
+
+def test_explicit_parent_beats_the_stack():
+    """``parent=`` hangs a span under a span that is not the stack's top
+    (an earlier tick's detached root) and inherits its sampling; a no-op
+    parent gives a no-op child."""
+    tr = Tracer(enabled=True)
+    first = tr.begin("first", detached=True)
+    with tr.span("second") as second:
+        with tr.span("child", parent=first) as child:
+            with tr.span("grandchild") as grand:
+                pass
+    first.end()
+    assert child.parent == first.sid and grand.parent == child.sid
+    assert second.parent is None
+    assert tr.begin("orphan", parent=NOOP_SPAN) is NOOP_SPAN
+    tr.configure(sampling=0.0)
+    unsampled = tr.begin("root", detached=True)
+    tr.configure(sampling=1.0)
+    tr.begin("child", parent=unsampled).end()
+    unsampled.end()
+    assert [s.name for s in tr.spans()] == ["grandchild", "child", "second",
+                                             "first"]
 
 
 def test_env_kill_switch_beats_enable(monkeypatch):
@@ -509,6 +535,100 @@ def test_served_tick_records_its_phase_tree(storage_index):
                  "nio_table", "cands_checked"):
         np.testing.assert_array_equal(np.asarray(getattr(a, name)),
                                       np.asarray(getattr(b, name)))
+
+
+class _Unready:
+    """The ``ids`` of a stub plan's result that reports not ready when its
+    launch returns (its wait and fetch then go through at once)."""
+
+    def __init__(self, ids):
+        self._ids = ids
+
+    def is_ready(self):
+        return False
+
+    def block_until_ready(self):
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._ids, dtype=dtype)
+
+
+def _alternate_readiness(q):
+    """Launches 1, 3, 5, ... of ``q`` report their result not ready; the
+    others return it complete. The loop then keeps ticks 0, 2, 4 in flight
+    and launches ticks 1, 3 behind them."""
+    real_fn, calls = q._fn, []
+
+    def fn(qs, valid):
+        calls.append(1)
+        res = jax.block_until_ready(real_fn(qs, valid))
+        if len(calls) % 2:
+            return dataclasses.replace(res, ids=_Unready(res.ids))
+        return res
+
+    q._fn = fn
+
+
+def _overlapped_ticks(plan: str) -> float:
+    entry = telemetry.get_registry().collect()[
+        "e2lsh_serve_overlapped_ticks_total"]
+    return sum(s["value"] for s in entry["samples"]
+               if s["labels"] == {"plan": plan})
+
+
+def test_overlapped_ticks_counter_matches_tick_stats(storage_index):
+    """e2lsh_serve_overlapped_ticks_total{plan} counts the TickStats rows
+    with ``overlapped`` set."""
+    idx, qs = storage_index
+    gc.collect()          # the collector sums every live queue of a plan
+    before = _overlapped_ticks("fused")
+    q = BatchQueue(SearchEngine(idx), plan="fused", ladder=(8,), k=2)
+    _alternate_readiness(q)
+    for lo in (0, 8, 16, 0, 8):               # five ticks of 8 rows
+        q.submit(qs[lo:lo + 8])
+    while q._step() is not None:
+        pass
+    assert [t.overlapped for t in q.tick_log] == [False, True, False, True,
+                                                  False]
+    assert _overlapped_ticks("fused") - before == 2
+    assert q.stats_summary()["overlap_share"] == pytest.approx(2 / 5)
+
+
+def test_interleaved_ticks_keep_their_own_span_trees(storage_index):
+    """Tick 1 is packed and launched while tick 0 is in flight, so its
+    spans begin inside tick 0's; each phase span still hangs under its own
+    tick's serve.tick, whose ``tick`` names the TickStats row."""
+    idx, qs = storage_index
+    q = BatchQueue(SearchEngine(idx), plan="fused", ladder=(8,), k=2)
+    _alternate_readiness(q)
+    q.submit(qs[:8])
+    q.submit(qs[8:16])
+    tr = telemetry.enable(sampling=1.0)
+    tr.clear()
+    q._step()                                 # tick 0 left in flight
+    q._step()                                 # tick 1 launched behind it
+    spans = tr.drain()
+    by_sid = {s.sid: s for s in spans}
+
+    def root_of(sp):
+        while sp.parent is not None:
+            sp = by_sid[sp.parent]
+        return sp
+
+    roots = sorted((s for s in spans if s.name == "serve.tick"),
+                   key=lambda s: s.attrs["tick"])
+    assert [r.attrs["tick"] for r in roots] == [0, 1]
+    assert all(r.parent is None for r in roots)
+    assert roots[0].ts_ns < roots[1].ts_ns < roots[0].ts_ns + roots[0].dur_ns
+    for r in roots:
+        mine = [s for s in spans if s.name in _TICK_TREE and root_of(s) is r]
+        assert sorted(s.name for s in mine) == sorted(_TICK_TREE)
+        for sp in mine:
+            parent = by_sid[sp.parent]
+            assert parent.name == _TICK_TREE[sp.name], sp.name
+            assert parent.ts_ns <= sp.ts_ns
+            assert sp.ts_ns + sp.dur_ns <= parent.ts_ns + parent.dur_ns
 
 
 def test_stats_summary_window_vs_reset_race(storage_index):
